@@ -171,6 +171,8 @@ def main():
                          "engine threads otherwise)")
     args = ap.parse_args()
 
+    from repro.common import use_compile_cache
+    use_compile_cache()
     from repro.configs.cronet import get_cronet_config
     from repro.fea import dataset as dsm
     from repro.fea import fea2d, train_cronet

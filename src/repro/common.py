@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -106,6 +107,28 @@ def param_bytes(specs: PyTree) -> int:
 # ---------------------------------------------------------------------------
 # Misc helpers
 # ---------------------------------------------------------------------------
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    cache there and nothing is set here. Otherwise the cache goes to
+    ``.jax_cache`` at the root of the checkout: a fixed path, so the
+    next run of any entry point from the same checkout finds it.
+    Every compile is kept, however short: JAX's default keeps only those
+    over one second, and a serving path compiles many small programs
+    (one per ladder rung and kernel) whose sum is its warm-up time.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        path = os.path.join(root, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def pad_to_multiple(x: int, m: int) -> int:

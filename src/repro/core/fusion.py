@@ -16,7 +16,7 @@ the two-touch HBM contract (one input DMA in, one output store).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,9 +45,12 @@ class FusionConfig:
 
 
 def infer(cfg: CRONetConfig, params: Dict, load_vol, hist,
-          fusion: FusionConfig = FusionConfig(), interpret: bool = True):
+          fusion: FusionConfig = FusionConfig(),
+          interpret: Optional[bool] = None):
     """CRONet inference under a fusion config. load_vol: (4,H,W,1);
-    hist: (T,ny,nx,1); returns (p,)."""
+    hist: (T,ny,nx,1); returns (p,). ``interpret=None`` lets every kernel
+    pick the Pallas interpreter on a CPU backend only
+    (``repro.kernels.resolve_interpret``)."""
     if fusion.path == "l2l3":
         return cronet_pipeline.cronet_fused(cfg, params, load_vol, hist,
                                             interpret=interpret)
@@ -55,7 +58,8 @@ def infer(cfg: CRONetConfig, params: Dict, load_vol, hist,
                       interpret=interpret)
 
 
-def _layerwise(cfg, params, load_vol, hist, l1: bool, interpret: bool):
+def _layerwise(cfg, params, load_vol, hist, l1: bool,
+               interpret: Optional[bool]):
     """Per-op kernel execution; with l1=False each activation is a separate
     pass over the tensor (the unfused baseline)."""
     tr, br = params["trunk"], params["branch"]

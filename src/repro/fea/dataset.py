@@ -26,8 +26,8 @@ data layer:
     anti-forgetting mix the fine-tune trains on.
 
 The single-trajectory MBB path (``train_cronet.build_dataset``) remains
-as a thin compatibility wrapper over ``run_simp`` so cached artifacts
-(benchmarks/precision.py) keep their exact numbers.
+as a thin compatibility wrapper over ``run_simp`` for its callers
+(benchmarks/precision.py, the examples).
 """
 from __future__ import annotations
 
@@ -151,12 +151,13 @@ def _make_simp_step_b(nelx: int, nely: int, rmin: float):
     dv = jnp.full((nely, nelx), 1.0 / (nelx * nely))
 
     @jax.jit
-    def step(bp: fea2d.BatchProblem, X, U):
-        U, _ = fea2d.solve_b(bp, X, U0=U)
+    def step(bp: fea2d.BatchProblem, X):
+        # from zero, like the serving step (make_hybrid_step: why)
+        U, _, broke = fea2d.solve_b(bp, X)
         c, dc = fea2d.compliance_and_sens_b(bp, X, U)
         dc_f = filt_b(X, dc)
         X_new = simp.oc_update_b(X, dc_f, dv, bp.volfrac)
-        return X_new, U, c
+        return X_new, U, c, broke
 
     return step
 
@@ -169,25 +170,28 @@ def run_simp_b(probs: Sequence[fea2d.Problem], n_iter: int = 60,
     densities AFTER each OC update, ``u``: the displacement of the solve
     that produced that update, ``c``: compliance) — the same recording
     convention ``simp.run_simp`` uses, so windowing code treats both
-    identically.
+    identically — plus ``broke``: whether that solve stopped at a CG
+    breakdown (``fea2d.solve_b``).
     """
     bp = fea2d.stack_problems(probs)
     step = _make_simp_step_b(bp.nelx, bp.nely, rmin)
     B = bp.batch
     X = jnp.broadcast_to(bp.volfrac[:, None, None],
                          (B, bp.nely, bp.nelx)).astype(jnp.float32)
-    U = jnp.zeros_like(bp.f)
-    xs, us, cs = [], [], []
+    xs, us, cs, bs = [], [], [], []
     for _ in range(n_iter):
-        X, U, c = step(bp, X, U)
+        X, U, c, broke = step(bp, X)
         xs.append(X)
         us.append(U)
         cs.append(c)
+        bs.append(broke)
     # one host transfer at the end instead of a per-iteration sync
     xs = np.asarray(jnp.stack(xs))          # (T, B, nely, nelx)
     us = np.asarray(jnp.stack(us))          # (T, B, ndof)
     cs = np.asarray(jnp.stack(cs))          # (T, B)
-    return [{"x": xs[:, b], "u": us[:, b], "c": cs[:, b]} for b in range(B)]
+    bs = np.asarray(jnp.stack(bs))          # (T, B)
+    return [{"x": xs[:, b], "u": us[:, b], "c": cs[:, b], "broke": bs[:, b]}
+            for b in range(B)]
 
 
 # ----------------------------------------------------------------- dataset
@@ -224,14 +228,18 @@ class TrajectoryDataset(NamedTuple):
 def window_trajectory(hist: Dict[str, np.ndarray], hist_len: int):
     """Sliding (hist_len)-windows over one SIMP history; the target is
     the displacement field of the solve that follows the window — the
-    exact quantity the hybrid loop asks the surrogate to replace."""
-    xs, us = hist["x"], hist["u"]
-    windows, targets = [], []
-    for i in range(hist_len, len(xs)):
-        windows.append(xs[i - hist_len:i])
-        targets.append(us[i])
-    return (np.stack(windows)[..., None].astype(np.float32),
-            np.stack(targets).astype(np.float32))
+    exact quantity the hybrid loop asks the surrogate to replace.
+
+    A window whose target solve stopped at a CG breakdown (``broke``,
+    recorded by ``run_simp_b``) is left out: that displacement is an
+    unconverged iterate, not the FEA answer."""
+    xs, us = np.asarray(hist["x"]), np.asarray(hist["u"])
+    broke = np.asarray(hist.get("broke", np.zeros(len(xs), bool)), bool)
+    keep = np.arange(hist_len, len(xs))
+    keep = keep[~broke[keep]]
+    windows = xs[keep[:, None] - hist_len + np.arange(hist_len)]
+    return (windows[..., None].astype(np.float32),
+            us[keep].astype(np.float32))
 
 
 def build_dataset(cfg: CRONetConfig,
@@ -245,6 +253,7 @@ def build_dataset(cfg: CRONetConfig,
     of ``batch`` stacked problems; every trajectory is then windowed and
     stacked with its own ``load_vol`` conditioning row, and ONE shared
     ``u_scale`` (max |u| over all targets) normalizes the whole set.
+    Targets whose solve broke down are dropped (``window_trajectory``).
     """
     if cases is None:
         cases = sample_load_cases(n_cases, seed=seed)

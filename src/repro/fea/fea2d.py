@@ -127,8 +127,11 @@ def stiffness_apply(prob: Problem, x_phys: jnp.ndarray, u: jnp.ndarray):
 
 
 def solve(prob: Problem, x_phys: jnp.ndarray, tol: float = 1e-6,
-          max_iter: int = 2000, u0=None):
-    """Jacobi-preconditioned CG on the free dofs. Returns (u, n_iters)."""
+          max_iter: int = 2000):
+    """Jacobi-preconditioned CG on the free dofs, from zero (see
+    ``solve_b``). Returns (u, n_iters, broke): ``broke`` is True when the
+    solve stopped at a CG breakdown (see ``solve_b``) instead of meeting
+    ``tol`` or ``max_iter``."""
     f = prob.f * prob.free_mask
     # diagonal of K for Jacobi preconditioner
     e = prob.e_min + (x_phys.reshape(-1) ** prob.penal) * (1 - prob.e_min)
@@ -141,33 +144,36 @@ def solve(prob: Problem, x_phys: jnp.ndarray, tol: float = 1e-6,
     def precond(r):
         return r / diag * prob.free_mask
 
-    u = jnp.zeros_like(f) if u0 is None else u0 * prob.free_mask
-    r = f - stiffness_apply(prob, x_phys, u)
+    u = jnp.zeros_like(f)
+    r = f
     z = precond(r)
     p = z
     rz = jnp.vdot(r, z)
     fnorm = jnp.linalg.norm(f)
 
     def cond(state):
-        u, r, p, rz, it = state
-        # fnorm == 0 (zero load) is converged by definition: without the
-        # guard a stale u0 leaves r != 0 and the relative criterion can
-        # never be met, so the slot burns max_iter iterations
-        return (jnp.linalg.norm(r) > tol * fnorm) & (fnorm > 0) & (it < max_iter)
+        u, r, p, rz, it, ok = state
+        return ok & (jnp.linalg.norm(r) > tol * fnorm) & (it < max_iter)
 
     def body(state):
-        u, r, p, rz, it = state
+        u, r, p, rz, it, ok = state
         kp = stiffness_apply(prob, x_phys, p)
-        alpha = rz / jnp.maximum(jnp.vdot(p, kp), 1e-30)
-        u = u + alpha * p
-        r = r - alpha * kp
-        z = precond(r)
-        rz_new = jnp.vdot(r, z)
-        p = z + (rz_new / jnp.maximum(rz, 1e-30)) * p
-        return u, r, p, rz_new, it + 1
+        pkp = jnp.vdot(p, kp)
+        # breakdown stop (see solve_b): keep the last finite iterate
+        ok = pkp > 0
+        alpha = rz / jnp.maximum(pkp, 1e-30)
+        u_n = u + alpha * p
+        r_n = r - alpha * kp
+        z = precond(r_n)
+        rz_new = jnp.vdot(r_n, z)
+        p_n = z + (rz_new / jnp.maximum(rz, 1e-30)) * p
+        return (jnp.where(ok, u_n, u), jnp.where(ok, r_n, r),
+                jnp.where(ok, p_n, p), jnp.where(ok, rz_new, rz),
+                it + ok.astype(jnp.int32), ok)
 
-    u, r, p, rz, it = jax.lax.while_loop(cond, body, (u, r, p, rz, jnp.zeros((), jnp.int32)))
-    return u, it
+    u, r, p, rz, it, ok = jax.lax.while_loop(
+        cond, body, (u, r, p, rz, jnp.zeros((), jnp.int32), fnorm >= 0))
+    return u, it, ~ok
 
 
 def compliance_and_sens(prob: Problem, x_phys: jnp.ndarray, u: jnp.ndarray):
@@ -464,7 +470,7 @@ def load_volume_b(bp: BatchProblem) -> jnp.ndarray:
 
 
 def solve_b(bp: BatchProblem, X, tol: float = 1e-6, max_iter: int = 2000,
-            U0=None, need=None, backend: str = "reference"):
+            need=None, backend: str = "reference"):
     """Batched Jacobi-preconditioned CG with per-slot convergence masking.
 
     Same update recurrence as ``solve``: each slot performs the identical
@@ -472,12 +478,28 @@ def solve_b(bp: BatchProblem, X, tol: float = 1e-6, max_iter: int = 2000,
     while-loop body) once its own residual criterion is met — so results
     are bitwise slot-invariant, while the loop trip count is the max over
     the still-active slots. A slot with f == 0 (an empty serving slot)
-    converges in zero iterations, even under a stale warm start (fnorm
-    == 0 means converged by definition — the relative criterion alone
-    could never be met). `need` (bool (B,)) marks slots whose solution
-    the caller will actually consume; the others are masked out
-    immediately so they burn zero iterations (their U stays the warm
-    start). Returns (U, per-slot iters).
+    converges in zero iterations. `need` (bool (B,)) marks slots whose
+    solution the caller will actually consume; the others are masked out
+    immediately so they burn zero iterations (their U stays zero).
+    Returns (U, per-slot iters, per-slot broke).
+
+    Every solve starts from zero. Warm starts do harm here: with SIMP's
+    1e9 stiffness contrast an error in the near-floating void regions
+    barely moves the residual, so a warm start keeps it, and where the
+    solve stops unconverged (the 60x20 mesh, mostly at ``max_iter``) it
+    grows from solve to solve (up to 2000x the converged field's
+    displacement). From zero, U depends on the design alone, and a
+    surrogate prediction never leaks into the FEA check that scores it.
+
+    Breakdown: with SIMP's 1e9 stiffness contrast, f32 rounding can
+    give a search direction a curvature ``p.Kp <= 0`` (or NaN) once the
+    residual stagnates; the step ``rz / p.Kp`` then overflows and the
+    NaN reaches the design. Such a slot stops at its last finite iterate,
+    which has NOT met ``tol``, and its ``broke`` flag is set, so callers
+    can tell it from a converged slot (the hybrid state counts them per
+    request, the dataset builder drops them as training targets). A slot
+    that stopped at ``max_iter`` is the other unconverged case; its
+    iteration count says so.
 
     ``backend`` selects the iteration engine: ``"reference"`` is this
     pure-XLA loop; ``"fused"`` dispatches to kernels/cg_fused.py, which
@@ -489,7 +511,7 @@ def solve_b(bp: BatchProblem, X, tol: float = 1e-6, max_iter: int = 2000,
     if backend == "fused":
         from repro.kernels import cg_fused
         return cg_fused.solve_b_fused(bp, X, tol=tol, max_iter=max_iter,
-                                      U0=U0, need=need)
+                                      need=need)
     if backend != "reference":
         raise ValueError(f"unknown CG backend {backend!r} "
                          "(expected 'reference' or 'fused')")
@@ -503,29 +525,30 @@ def solve_b(bp: BatchProblem, X, tol: float = 1e-6, max_iter: int = 2000,
     def precond(R):
         return R / diag * bp.free_mask
 
-    U = jnp.zeros_like(F) if U0 is None else U0 * bp.free_mask
-    R = F - stiffness_apply_b(bp, X, U)
+    U = jnp.zeros_like(F)
+    R = F
     Z = precond(R)
-    P = Z
     RZ = tree_dot(R, Z)
     fnorm = tree_norm(F)
 
-    def active_of(R, its):
-        # the fnorm > 0 term makes zero-load slots converged by
-        # definition (see docstring) — without it a nonzero warm-start
-        # residual would keep an idle slot active for max_iter trips
-        return (need & (tree_norm(R) > tol * fnorm) & (fnorm > 0)
-                & (its < max_iter))
+    def active_of(R, its, ok):
+        return need & ok & (tree_norm(R) > tol * fnorm) & (its < max_iter)
 
     def cond(state):
-        U, R, P, RZ, its = state
-        return jnp.any(active_of(R, its))
+        U, R, P, RZ, its, ok = state
+        return jnp.any(active_of(R, its, ok))
 
     def body(state):
-        U, R, P, RZ, its = state
-        act = active_of(R, its)
+        U, R, P, RZ, its, ok = state
         KP = stiffness_apply_b(bp, X, P)
-        alpha = RZ / jnp.maximum(tree_dot(P, KP), 1e-30)
+        pKp = tree_dot(P, KP)
+        # breakdown stop (docstring): only an active slot can break down;
+        # a frozen one keeps its last P, so its pKp says nothing new
+        act = active_of(R, its, ok)
+        good = pKp > 0
+        ok = ok & (good | ~act)
+        act = act & good
+        alpha = RZ / jnp.maximum(pKp, 1e-30)
         U_n = U + alpha[:, None] * P
         R_n = R - alpha[:, None] * KP
         Z = precond(R_n)
@@ -534,8 +557,10 @@ def solve_b(bp: BatchProblem, X, tol: float = 1e-6, max_iter: int = 2000,
         m = act[:, None]
         return (jnp.where(m, U_n, U), jnp.where(m, R_n, R),
                 jnp.where(m, P_n, P), jnp.where(act, RZ_n, RZ),
-                its + act.astype(jnp.int32))
+                its + act.astype(jnp.int32), ok)
 
-    its0 = jnp.zeros((F.shape[0],), jnp.int32)
-    U, R, P, RZ, its = jax.lax.while_loop(cond, body, (U, R, Z, RZ, its0))
-    return U, its
+    B = F.shape[0]
+    U, R, P, RZ, its, ok = jax.lax.while_loop(
+        cond, body, (U, R, Z, RZ, jnp.zeros((B,), jnp.int32),
+                     jnp.ones((B,), bool)))
+    return U, its, ~ok

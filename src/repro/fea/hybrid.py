@@ -51,7 +51,6 @@ def cast_params(params, precision: str):
 class HybridState(NamedTuple):
     """Stacked per-slot optimization state (leading axis B)."""
     x: jnp.ndarray          # (B, nely, nelx) densities
-    u: jnp.ndarray          # (B, ndof) last accepted displacement
     hist: jnp.ndarray       # (B, T, nely, nelx) density ring buffer, oldest first
     it: jnp.ndarray         # (B,) int32 per-slot iteration counter
     err: jnp.ndarray        # (B,) last measured CRONet relative L2 error
@@ -64,6 +63,9 @@ class HybridState(NamedTuple):
     #                         them here is what lets the serving engine
     #                         report "where the fallback budget went"
     #                         without any extra device work)
+    cg_breakdowns: jnp.ndarray  # (B,) int32 FEA fallbacks whose CG stopped
+    #                             at a breakdown (fea2d.solve_b) instead of
+    #                             converging or reaching max_iter
 
 
 def init_state(cfg: CRONetConfig, bp: fea2d.BatchProblem) -> HybridState:
@@ -78,7 +80,6 @@ def init_state(cfg: CRONetConfig, bp: fea2d.BatchProblem) -> HybridState:
     # aliased leaves would be donated twice
     return HybridState(
         x=x0,
-        u=jnp.zeros_like(bp.f),
         hist=jnp.zeros((B, cfg.hist_len, bp.nely, bp.nelx), jnp.float32),
         it=jnp.zeros((B,), jnp.int32),
         err=jnp.full((B,), jnp.inf, jnp.float32),
@@ -86,6 +87,7 @@ def init_state(cfg: CRONetConfig, bp: fea2d.BatchProblem) -> HybridState:
         n_fea=jnp.zeros((B,), jnp.int32),
         compliance=jnp.zeros((B,), jnp.float32),
         cg_iters=jnp.zeros((B,), jnp.int32),
+        cg_breakdowns=jnp.zeros((B,), jnp.int32),
     )
 
 
@@ -98,7 +100,6 @@ def reset_slot(cfg: CRONetConfig, state: HybridState, i: int,
         x0 = x0 * elem_mask
     return HybridState(
         x=state.x.at[i].set(x0),
-        u=state.u.at[i].set(0.0),
         hist=state.hist.at[i].set(0.0),
         it=state.it.at[i].set(0),
         err=state.err.at[i].set(jnp.inf),
@@ -106,6 +107,7 @@ def reset_slot(cfg: CRONetConfig, state: HybridState, i: int,
         n_fea=state.n_fea.at[i].set(0),
         compliance=state.compliance.at[i].set(0.0),
         cg_iters=state.cg_iters.at[i].set(0),
+        cg_breakdowns=state.cg_breakdowns.at[i].set(0),
     )
 
 
@@ -113,7 +115,7 @@ def park_slot(state: HybridState, i: int) -> HybridState:
     """Gather lane i to host numpy (preemption parking).
 
     The parked tuple is a complete per-slot optimization snapshot
-    (density, displacement, history ring, gate bookkeeping); host-side so
+    (density, history ring, gate bookkeeping); host-side so
     it can be re-admitted on any shard/device. Restoring it with
     ``restore_slot`` and stepping resumes the trajectory bitwise — every
     op in the batched step is slot-invariant, and gather/scatter of a
@@ -156,11 +158,12 @@ def resize_state(state: HybridState, new_b: int) -> HybridState:
         return jnp.concatenate([leaf, extra], axis=0)
 
     return HybridState(
-        x=pad(state.x, 0.5), u=pad(state.u, 0.0), hist=pad(state.hist, 0.0),
+        x=pad(state.x, 0.5), hist=pad(state.hist, 0.0),
         it=pad(state.it, 0), err=pad(state.err, jnp.inf),
         n_cronet=pad(state.n_cronet, 0), n_fea=pad(state.n_fea, 0),
         compliance=pad(state.compliance, 0.0),
-        cg_iters=pad(state.cg_iters, 0))
+        cg_iters=pad(state.cg_iters, 0),
+        cg_breakdowns=pad(state.cg_breakdowns, 0))
 
 
 def _oracle_forward(cfg: CRONetConfig):
@@ -244,15 +247,17 @@ def make_hybrid_step(cfg: CRONetConfig, u_scale: float,
                       & (state.it % verify_every != 0))
         need_fea = ~use_cronet
 
-        # the masked CG reports per-slot iteration counts alongside U;
-        # carrying them through the state (zeros when no slot needed FEA)
-        # costs nothing on-device and gives the serving engine the
-        # CG-fallback budget per request
-        u_fea, cg_its = jax.lax.cond(
+        # the masked CG reports per-slot iteration counts and breakdown
+        # flags alongside U; carrying them through the state (zeros when
+        # no slot needed FEA) costs nothing on-device and gives the
+        # serving engine the CG-fallback budget per request. Each solve
+        # starts from zero, never from state (solve_b docstring: why).
+        u_fea, cg_its, cg_broke = jax.lax.cond(
             jnp.any(need_fea),
-            lambda: fea2d.solve_b(bp, state.x, U0=state.u,
-                                  need=need_fea, backend=fea_backend),
-            lambda: (state.u, jnp.zeros_like(state.cg_iters)))
+            lambda: fea2d.solve_b(bp, state.x, need=need_fea,
+                                  backend=fea_backend),
+            lambda: (jnp.zeros_like(bp.f), jnp.zeros_like(state.cg_iters),
+                     jnp.zeros_like(need_fea)))
 
         # batch-invariant norms: err is COMPARED against the gate threshold,
         # so it must be bitwise-identical at any batch width
@@ -286,10 +291,11 @@ def make_hybrid_step(cfg: CRONetConfig, u_scale: float,
             x = simp.oc_update_b(state.x, dc_f, dv, bp.volfrac,
                                  mask=bp.elem_mask)
         return HybridState(
-            x=x, u=u, hist=hist, it=state.it + 1, err=err,
+            x=x, hist=hist, it=state.it + 1, err=err,
             n_cronet=state.n_cronet + use_cronet.astype(jnp.int32),
             n_fea=state.n_fea + need_fea.astype(jnp.int32), compliance=c,
-            cg_iters=state.cg_iters + cg_its.astype(jnp.int32))
+            cg_iters=state.cg_iters + cg_its.astype(jnp.int32),
+            cg_breakdowns=state.cg_breakdowns + cg_broke.astype(jnp.int32))
 
     # tracing telemetry: trace_count[0] is the number of XLA compilations
     # this step has triggered (one per distinct batch width). The serving
@@ -359,7 +365,7 @@ def run_hybrid(cfg: CRONetConfig, params, u_scale: float,
     c_ref = float(reference["c"][-1])
     # solution quality = FEA-evaluated compliance of the FINAL DESIGN (the
     # quantity topology optimization minimizes), not the last surrogate u.
-    u_fin, _ = fea2d.solve(prob, x, u0=state.u[0])
+    u_fin, _, _ = fea2d.solve(prob, x)
     c_fin, _ = fea2d.compliance_and_sens(prob, x, u_fin)
     c_fin = float(c_fin)
     acc = 100.0 * max(0.0, 1.0 - abs(c_fin - c_ref) / abs(c_ref))

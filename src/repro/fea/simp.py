@@ -132,24 +132,24 @@ class SimpState(NamedTuple):
 def run_simp(prob: fea2d.Problem, n_iter: int = 60, rmin: float = 1.5,
              solver: Optional[Callable] = None, record_every: int = 1,
              x0=None):
-    """Reference SIMP loop. solver(x_phys, u_prev) -> (u, c, dc); defaults
-    to FEA. Returns (final_state, history dict of arrays)."""
+    """Reference SIMP loop. solver(x_phys) -> (u, c, dc); defaults to FEA
+    (``fea2d.solve``, from zero every iteration). Returns (final_state,
+    history dict of arrays)."""
     filt = make_filter(prob.nelx, prob.nely, rmin)
 
-    def fea_solver(x_phys, u_prev):
-        u, _ = fea2d.solve(prob, x_phys, u0=u_prev)
+    def fea_solver(x_phys):
+        u, _, _ = fea2d.solve(prob, x_phys)
         c, dc = fea2d.compliance_and_sens(prob, x_phys, u)
         return u, c, dc
 
     solver = solver or fea_solver
     x = (jnp.full((prob.nely, prob.nelx), prob.volfrac)
          if x0 is None else x0)
-    u = jnp.zeros_like(prob.f)
     dv = jnp.ones_like(x) / x.size
 
     xs, us, cs = [], [], []
     for it in range(n_iter):
-        u, c, dc = solver(x, u)
+        u, c, dc = solver(x)
         dc_f = filt(x, dc)
         x = oc_update(x, dc_f, dv, prob.volfrac)
         if it % record_every == 0:

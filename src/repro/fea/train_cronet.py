@@ -35,9 +35,9 @@ def build_dataset(cfg: CRONetConfig, n_iter: int = 100, rmin: float = 1.5):
     """Legacy single-MBB-trajectory dataset: returns (load_vol, hists
     (N,T,ny,nx,1), targets (N, ndof), u_scale, reference history).
 
-    Kept verbatim (unbatched ``run_simp``) so cached artifacts keep
-    their exact numbers; new code should use ``fea.dataset.build_dataset``
-    — the multi-load-case path the serving stack is trained on.
+    Kept (unbatched ``run_simp``) for the legacy 5-tuple's callers; new
+    code should use ``fea.dataset.build_dataset`` — the multi-load-case
+    path the serving stack is trained on.
     """
     prob = fea2d.mbb_problem(cfg.nelx, cfg.nely)
     _, hist = simp.run_simp(prob, n_iter=n_iter, rmin=rmin)

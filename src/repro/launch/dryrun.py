@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh (16x16 single-pod / 2x16x16 multi-pod), with NO array

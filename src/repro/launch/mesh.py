@@ -7,6 +7,17 @@ dry-run forces 512 host devices while tests/benches run on 1.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the model code places arrays
+    with sharding constraints and leaves the rest to the partitioner.
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which those
+    constraints become part of the array types and, for example, the KV
+    cache update in ``transformer.apply_attn`` (an unsharded cache slice
+    and a constrained update) is a sharding type error."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,12 +30,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many devices exist (tests/smoke)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def batch_axes(mesh) -> tuple:

@@ -471,6 +471,9 @@ class TopoServingEngine:
             "topo_cg_iters",
             "CG iterations burned by a completed request's FEA fallbacks",
             buckets=obs_metrics.DEFAULT_COUNT_BUCKETS)
+        self._m_cg_broke = m.counter(
+            "topo_cg_breakdowns_total",
+            "FEA fallbacks whose CG stopped at a breakdown, unconverged")
         self._m_done = m.counter(
             "topo_completions_total",
             "completed requests by (mesh, deadline outcome)")
@@ -793,6 +796,7 @@ class TopoServingEngine:
         req.cronet_iters = int(shard.state.n_cronet[lane])
         req.fea_iters = int(shard.state.n_fea[lane])
         req.cg_iters = int(shard.state.cg_iters[lane])
+        req.cg_breakdowns = int(shard.state.cg_breakdowns[lane])
         req.model_tag = self.model_tag
         t_done = time.monotonic()    # deadline math: monotonic, like submit
         req.completed_t = time.time()  # user-facing wall-clock stamp
@@ -826,6 +830,8 @@ class TopoServingEngine:
             self._m_iters.inc(req.fea_iters, mesh=self._mesh_label,
                               path="fea")
         self._m_cg.observe(req.cg_iters, mesh=self._mesh_label)
+        if req.cg_breakdowns:
+            self._m_cg_broke.inc(req.cg_breakdowns, mesh=self._mesh_label)
         # the np.asarray above synced through every dispatched step:
         # close the timing window and update the per-step estimate
         if shard.steps_in_window > 0 and shard.busy_t0 is not None:

@@ -147,6 +147,8 @@ class TopoRequest:
     #                                         fallbacks burned (hybrid
     #                                         state carries the per-slot
     #                                         counter; no extra syncs)
+    cg_breakdowns: int = 0                  # FEA fallbacks whose CG stopped
+    #                                         at a breakdown, unconverged
     latency_s: float = 0.0                  # first slot admission -> completion
     queue_wait_s: float = 0.0               # submit -> first slot admission
     deadline_met: Optional[bool] = None     # None when no deadline was set

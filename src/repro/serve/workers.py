@@ -318,7 +318,8 @@ class RemoteEngine:
 
     #: completion fields copied worker -> parent request object
     _COPY = ("done", "completed_t", "density", "compliance",
-             "cronet_iters", "fea_iters", "cg_iters", "latency_s",
+             "cronet_iters", "fea_iters", "cg_iters", "cg_breakdowns",
+             "latency_s",
              "queue_wait_s", "deadline_met", "preemptions", "model_tag",
              "admitted_t", "trace")
 
@@ -696,6 +697,13 @@ class _WorkerHandle:
 class WorkerPool:
     """Spawn, lease to, monitor, and recover N engine-worker processes.
 
+    A CPU-only mechanism: an accelerator belongs to one process, and a
+    parent that has initialized JAX on one already holds it, so spawned
+    workers could not reach the chip. The pool therefore refuses to start
+    unless the parent's JAX backend is the CPU. On an accelerator host,
+    one process drives every local device instead (``TopoServingEngine``
+    pins its shards to them).
+
     Parameters
     ----------
     n_workers :        process count (the scaling knob).
@@ -735,6 +743,14 @@ class WorkerPool:
                  metrics=None):
         if n_workers < 1:
             raise ValueError(f"n_workers must be >= 1, got {n_workers}")
+        import jax
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"WorkerPool is CPU-only: this process holds the "
+                f"{backend!r} backend, and spawned workers cannot reach a "
+                f"device another process owns. Serve in-process instead "
+                f"(TopoGateway without workers=).")
         import multiprocessing
         # spawn, not fork: a forked child would inherit the parent's JAX
         # runtime state (device buffers, compiled executables, thread

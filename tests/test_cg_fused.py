@@ -4,8 +4,8 @@ The whole suite pins ONE invariant from three directions: the fused
 solve (``fea2d.solve_b(..., backend="fused")`` — the entire Jacobi-PCG
 loop inside a single pallas_call) is a pure deployment knob. Densities,
 displacements, and per-slot iteration counts are BITWISE-equal to the
-reference XLA path across batch widths, warm starts, ``need`` masks,
-and shape-class ``elem_mask`` padding; the serving engine on the fused
+reference XLA path across batch widths, ``need`` masks, zero-load
+slots and shape-class ``elem_mask`` padding; the serving engine on the fused
 backend keeps the no-recompilation streaming contract; and every
 kernel entry point resolves ``interpret=None`` by platform
 auto-detection instead of hardwiring the interpreter.
@@ -45,23 +45,23 @@ def _probs(n, nelx=12, nely=4):
         load=(0.05 * i, -1.0 - 0.1 * i)) for i in range(n)]
 
 
-def _solve_both(bp, X, U0=None, need=None):
+def _solve_both(bp, X, need=None):
     # jitted with (bp, X, ...) as traced arguments — the same calling
     # convention as the engine's compiled tick, the contract's domain
-    ref = jax.jit(lambda b, x, u, n: fea2d.solve_b(b, x, U0=u, need=n))(
-        bp, X, U0, need)
-    fus = jax.jit(lambda b, x, u, n: fea2d.solve_b(b, x, U0=u, need=n,
-                                                   backend="fused"))(
-        bp, X, U0, need)
+    ref = jax.jit(lambda b, x, n: fea2d.solve_b(b, x, need=n))(bp, X, need)
+    fus = jax.jit(lambda b, x, n: fea2d.solve_b(b, x, need=n,
+                                                backend="fused"))(bp, X, need)
     return ref, fus
 
 
 def _assert_bitwise(ref, fus, msg):
-    (ur, ir), (uf, if_) = ref, fus
+    (ur, ir, br), (uf, if_, bf) = ref, fus
     np.testing.assert_array_equal(np.asarray(ur), np.asarray(uf),
                                   err_msg=f"{msg}: U diverged")
     np.testing.assert_array_equal(np.asarray(ir), np.asarray(if_),
                                   err_msg=f"{msg}: iteration counts diverged")
+    np.testing.assert_array_equal(np.asarray(br), np.asarray(bf),
+                                  err_msg=f"{msg}: breakdown flags diverged")
 
 
 # --------------------------------------------------- bitwise equivalence
@@ -77,19 +77,18 @@ def test_fused_bitwise_across_widths(width):
 
 
 def test_fused_bitwise_warm_start_and_need_mask():
-    """Warm starts (U0 from a truncated solve) and partial ``need``
-    masks — the serving tick's actual calling convention — stay
-    bitwise. Slots with need=False must come back untouched."""
+    """Partial ``need`` masks — the serving tick's actual calling
+    convention, where every solve starts from zero (fea2d.solve_b) —
+    stay bitwise. Slots with need=False come back zero, untouched."""
     bp = fea2d.stack_problems(_probs(3))
     X = jnp.stack([jnp.full((4, 12), 0.5)] * 3)
-    U0, _ = fea2d.solve_b(bp, X, max_iter=5)          # stale warm start
     need = jnp.asarray([True, False, True])
-    ref, fus = _solve_both(bp, X, U0=U0, need=need)
-    _assert_bitwise(ref, fus, msg="warm start + need mask")
-    # the frozen slot keeps its warm start and burns zero iterations
-    np.testing.assert_array_equal(np.asarray(ref[0][1]),
-                                  np.asarray(U0 * bp.free_mask)[1])
+    ref, fus = _solve_both(bp, X, need=need)
+    _assert_bitwise(ref, fus, msg="need mask")
+    # the frozen slot stays at zero and burns zero iterations
+    assert not np.asarray(ref[0][1]).any()
     assert int(ref[1][1]) == int(fus[1][1]) == 0
+    assert int(ref[1][0]) > 0 and int(ref[1][2]) > 0
 
 
 def test_fused_bitwise_under_elem_mask_padding():
@@ -107,20 +106,15 @@ def test_fused_bitwise_under_elem_mask_padding():
 
 
 def test_zero_load_slot_with_stale_warm_start_converges_immediately():
-    """Regression: a slot with f == 0 (empty serving lane) but a nonzero
-    stale warm start used to burn max_iter iterations — the residual
-    R = -K U0 is nonzero while the tolerance tol * ||F|| is exactly
-    zero, so ``rnorm > tol * fnorm`` never went false. The fnorm > 0
-    convergence term makes such slots converged by definition, on BOTH
-    backends."""
+    """Regression: a slot with f == 0 (empty serving lane) used to burn
+    max_iter iterations when a previous occupant's displacement was
+    handed in as its start. Solves now start from zero, so R = F = 0
+    meets ``rnorm > tol * fnorm`` at once, on BOTH backends."""
     live = _probs(1)[0]
     idle = live._replace(f=jnp.zeros_like(live.f))     # load-free lane
     bp = fea2d.stack_problems([live, idle])
     X = jnp.stack([jnp.full((4, 12), 0.5)] * 2)
-    # stale state from a previous occupant of the lane
-    U0 = jnp.stack([jnp.zeros(live.f.shape[0], jnp.float32),
-                    jnp.full((live.f.shape[0],), 0.37, jnp.float32)])
-    ref, fus = _solve_both(bp, X, U0=U0)
+    ref, fus = _solve_both(bp, X)
     _assert_bitwise(ref, fus, msg="zero-load slot")
     its = np.asarray(ref[1])
     assert its[1] == 0, f"idle slot burned {its[1]} iterations"
@@ -148,7 +142,9 @@ def test_resolve_interpret_auto_detects_platform():
 def test_kernel_entry_points_default_to_auto_detection():
     """Regression: kernel entry points used to hardwire interpret=True,
     silently running the Pallas interpreter on accelerator hosts. Every
-    public entry's ``interpret`` default must now be None (auto)."""
+    public entry's ``interpret`` default must now be None (auto),
+    including the fusion-config dispatcher ``fusion.infer``."""
+    from repro.core import fusion
     from repro.kernels import (cg_fused, conv, cronet_pipeline,
                                flash_attention, gemm, pool, silu, slstm)
     entries = [conv.conv2d, conv.conv3d, gemm.gemm, pool.maxpool2d,
@@ -156,7 +152,8 @@ def test_kernel_entry_points_default_to_auto_detection():
                silu.silu_lut, silu.silu_exact, slstm.slstm_fused,
                flash_attention.flash_attention,
                flash_attention.flash_attention_causal_gqa,
-               cronet_pipeline.cronet_fused, cg_fused.solve_b_fused]
+               cronet_pipeline.cronet_fused, cg_fused.solve_b_fused,
+               fusion.infer]
     for fn in entries:
         default = inspect.signature(fn).parameters["interpret"].default
         assert default is None, (

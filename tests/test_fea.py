@@ -40,7 +40,7 @@ def test_stiffness_linearity(a, b):
 
 def test_cg_solves(prob):
     x = jnp.full((prob.nely, prob.nelx), 0.5)
-    u, it = fea2d.solve(prob, x)
+    u, it, _ = fea2d.solve(prob, x)
     r = prob.f * prob.free_mask - fea2d.stiffness_apply(prob, x, u)
     rel = float(jnp.linalg.norm(r) / jnp.linalg.norm(prob.f))
     assert rel < 5e-4      # fp32 CG floor on ill-conditioned SIMP stiffness
@@ -49,16 +49,16 @@ def test_cg_solves(prob):
 
 def test_fixed_dofs_zero(prob):
     x = jnp.full((prob.nely, prob.nelx), 0.5)
-    u, _ = fea2d.solve(prob, x)
+    u, _, _ = fea2d.solve(prob, x)
     fixed = np.where(np.asarray(prob.free_mask) == 0)[0]
     np.testing.assert_allclose(np.asarray(u)[fixed], 0.0)
 
 
 def test_denser_is_stiffer(prob):
     """More material => lower compliance (monotonicity)."""
-    u1, _ = fea2d.solve(prob, jnp.full((prob.nely, prob.nelx), 0.3))
+    u1, _, _ = fea2d.solve(prob, jnp.full((prob.nely, prob.nelx), 0.3))
     c1, _ = fea2d.compliance_and_sens(prob, jnp.full((prob.nely, prob.nelx), 0.3), u1)
-    u2, _ = fea2d.solve(prob, jnp.full((prob.nely, prob.nelx), 0.9))
+    u2, _, _ = fea2d.solve(prob, jnp.full((prob.nely, prob.nelx), 0.9))
     c2, _ = fea2d.compliance_and_sens(prob, jnp.full((prob.nely, prob.nelx), 0.9), u2)
     assert float(c2) < float(c1)
 
@@ -66,7 +66,7 @@ def test_denser_is_stiffer(prob):
 def test_sensitivities_negative(prob):
     """dC/dx <= 0 everywhere: adding material never hurts compliance."""
     x = jnp.full((prob.nely, prob.nelx), 0.5)
-    u, _ = fea2d.solve(prob, x)
+    u, _, _ = fea2d.solve(prob, x)
     _, dc = fea2d.compliance_and_sens(prob, x, u)
     assert float(jnp.max(dc)) <= 1e-9
 
@@ -121,8 +121,8 @@ def test_padded_solve_matches_original_physics():
     pp = fea2d.pad_problem(p, 12, 6)
     xo = jnp.full((4, 10), p.volfrac)
     xp = jnp.asarray(np.asarray(pp.elem_mask) * p.volfrac)
-    uo, _ = fea2d.solve(p, xo)
-    up, _ = fea2d.solve(pp, xp)
+    uo, _, _ = fea2d.solve(p, xo)
+    up, _, _ = fea2d.solve(pp, xp)
     co, dco = fea2d.compliance_and_sens(p, xo, uo)
     cp, dcp = fea2d.compliance_and_sens(pp, xp, up)
     assert np.isclose(float(co), float(cp), rtol=1e-4)
@@ -200,3 +200,57 @@ def test_load_volume_layout(prob):
     assert float(vol[1, 0, 0, 0]) == -1.0
     # left edge x-support flags set
     assert float(vol[2, :, 0, 0].sum()) == prob.nely + 1
+
+
+def test_cg_breakdown_keeps_designs_finite_at_large_mesh():
+    """Regression: on the published 60x20 mesh the f32 Jacobi-PCG of
+    this load case stagnates in the 9th SIMP iteration, a search
+    direction comes out with p.Kp <= 0, and the step used to overflow
+    into NaN densities. A slot now stops at its last finite iterate and
+    reports the breakdown — on the reference batched path, on the fused
+    kernel (bitwise-equal under jit), and on the unbatched solve behind
+    simp.run_simp — and the dataset leaves that target out."""
+    from repro.fea import dataset as dsm
+
+    case = dsm.sample_load_cases(2, seed=0)[1]
+    prob = case.problem(60, 20)
+    hist = dsm.run_simp_b([prob, fea2d.idle_problem(60, 20)], n_iter=10)[0]
+    assert np.isfinite(hist["x"]).all() and np.isfinite(hist["c"]).all()
+    assert hist["broke"][8]
+    w, tg = dsm.window_trajectory(hist, 3)
+    assert len(tg) == 10 - 3 - int(hist["broke"][3:].sum())
+
+    bp = fea2d.stack_problems([prob, prob])
+    X = jnp.asarray(np.stack([hist["x"][7]] * 2))
+    solve = jax.jit(lambda b, x, backend: fea2d.solve_b(b, x, backend=backend),
+                    static_argnums=2)
+    ur, ir, br = solve(bp, X, "reference")
+    uf, if_, bf = solve(bp, X, "fused")
+    assert np.isfinite(np.asarray(ur)).all()
+    np.testing.assert_array_equal(np.asarray(ur), np.asarray(uf))
+    np.testing.assert_array_equal(np.asarray(ir), np.asarray(if_))
+    np.testing.assert_array_equal(np.asarray(br), np.asarray(bf))
+    assert np.asarray(br).all()
+
+    _, ref = simp.run_simp(prob, n_iter=10)
+    assert np.isfinite(ref["x"]).all() and np.isfinite(ref["c"]).all()
+
+
+def test_window_trajectory_drops_broken_down_targets():
+    """A target whose solve stopped at a CG breakdown is an unconverged
+    iterate: build_dataset's windowing leaves it out, for every caller."""
+    from repro.fea import dataset as dsm
+
+    T, hist_len = 8, 3
+    hist = {"x": np.arange(T, dtype=np.float32)[:, None, None]
+                 * np.ones((T, 2, 3), np.float32),
+            "u": np.arange(T, dtype=np.float32)[:, None]
+                 * np.ones((T, 5), np.float32),
+            "broke": np.isin(np.arange(T), [4, 6])}
+    w, tg = dsm.window_trajectory(hist, hist_len)
+    assert tg[:, 0].tolist() == [3, 5, 7]
+    assert w.shape == (3, hist_len, 2, 3, 1)
+    assert w[:, :, 0, 0, 0].tolist() == [[0, 1, 2], [2, 3, 4], [4, 5, 6]]
+    hist["broke"][:] = True
+    w, tg = dsm.window_trajectory(hist, hist_len)
+    assert w.shape == (0, hist_len, 2, 3, 1) and tg.shape == (0, 5)

@@ -161,3 +161,30 @@ def test_input_specs_cover_all_cells():
                        for l in jax.tree.leaves(specs))
             n += 1
     assert n == 31   # 40 assigned cells minus 9 documented skips
+
+
+@pytest.mark.parametrize("env_dir", [None, "placed"])
+def test_use_compile_cache_places_the_cache(monkeypatch, tmp_path, env_dir):
+    """Entry points keep JAX's persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says and then set no directory themselves;
+    without it the cache goes to the fixed ``.jax_cache`` at the root of
+    the checkout. Either way every compile is kept, however short."""
+    from repro import common
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    updates = []
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert common.use_compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want),
+                           ("jax_persistent_cache_min_compile_time_secs", 0)]
+    else:
+        placed = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        assert common.use_compile_cache() == placed
+        assert updates == [("jax_persistent_cache_min_compile_time_secs", 0)]
+    assert jax.config.jax_compilation_cache_dir == before

@@ -146,9 +146,9 @@ def test_solve_b_matches_single_solve(cfg):
     bp = fea2d.stack_problems(probs)
     rng = np.random.default_rng(0)
     X = jnp.asarray(rng.uniform(0.3, 0.9, (3, 4, 12)).astype(np.float32))
-    U, its = fea2d.solve_b(bp, X)
+    U, its, _ = fea2d.solve_b(bp, X)
     for i, p in enumerate(probs):
-        u_ref, _ = fea2d.solve(p, X[i])
+        u_ref, _, _ = fea2d.solve(p, X[i])
         np.testing.assert_allclose(np.asarray(U[i]), np.asarray(u_ref),
                                    rtol=1e-3, atol=1e-5)
         # residual check: K u == f on free dofs (fp32 CG floor on SIMP
@@ -164,9 +164,37 @@ def test_idle_slot_costs_zero_cg_iterations(cfg):
     probs = [_problems(1)[0], fea2d.idle_problem(12, 4)]
     bp = fea2d.stack_problems(probs)
     X = jnp.full((2, 4, 12), 0.5)
-    _, its = fea2d.solve_b(bp, X)
+    _, its, _ = fea2d.solve_b(bp, X)
     assert int(its[1]) == 0
     assert int(its[0]) > 0
+
+
+def test_cg_breakdowns_reach_request_and_counter(cfg, params, monkeypatch):
+    """A CG breakdown in an FEA fallback is counted per request and in
+    ``topo_cg_breakdowns_total``, not mistaken for a converged solve."""
+    from repro.obs import metrics as obs_metrics
+
+    solve_b = fea2d.solve_b
+
+    def always_broke(*args, **kwargs):
+        U, its, broke = solve_b(*args, **kwargs)
+        return U, its, jnp.ones_like(broke)
+
+    monkeypatch.setattr(fea2d, "solve_b", always_broke)
+    # an error_threshold no other test uses: a fresh (uncached) step
+    reg = obs_metrics.MetricsRegistry()
+    eng = TopoServingEngine(cfg, params, u_scale=U_SCALE, slots=2,
+                            precision="fp32", error_threshold=0.0123,
+                            metrics=reg)
+    (r,) = eng.run([TopoRequest(uid=0, problem=_problems(1)[0], n_iter=5)])
+    assert r.fea_iters == 5 and r.cg_breakdowns == 5
+    assert reg.counter("topo_cg_breakdowns_total").value(mesh="12x4") == 5
+
+    monkeypatch.setattr(fea2d, "solve_b", solve_b)
+    eng = TopoServingEngine(cfg, params, u_scale=U_SCALE, slots=2,
+                            precision="fp32", metrics=reg)
+    (r,) = eng.run([TopoRequest(uid=1, problem=_problems(1)[0], n_iter=5)])
+    assert r.cg_breakdowns == 0
 
 
 def test_tree_sum_matches_sum():

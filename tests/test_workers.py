@@ -104,6 +104,23 @@ def _preq(uid, priority=0, deadline_s=None):
     return req
 
 
+def test_pool_refuses_non_cpu_backend(monkeypatch):
+    """Workers are a CPU-only mechanism: a parent whose JAX backend is an
+    accelerator already owns the device, so the pool must refuse before
+    spawning a single worker."""
+    import jax
+
+    from repro.serve.workers import WorkerPool
+
+    spawned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(WorkerPool, "_spawn",
+                        lambda self: spawned.append(self))
+    with pytest.raises(RuntimeError, match="CPU-only"):
+        WorkerPool(1, heartbeat_s=0)
+    assert not spawned
+
+
 def test_crash_split_fails_admitted_typed_and_requeues_in_edf_order():
     h0 = _StubHandle(worker_id=0)
     eng = _stub_proxy(h0)
