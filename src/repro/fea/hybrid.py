@@ -238,58 +238,66 @@ def make_hybrid_step(cfg: CRONetConfig, u_scale: float,
                            state.hist[..., None].astype(dtype))  # (B, p)
             return cronet.decode_to_dofs(cfg, pred) * u_scale * bp.free_mask
 
-        # pre-warm-up no slot can consume or score the prediction, so skip
+        # named scopes label the step's regions in the compiled HLO and the
+        # profiler trace (metadata only: the arithmetic is unchanged).
+        # Pre-warm-up no slot can consume or score the prediction, so skip
         # the forward entirely (it is the whole step cost on the
         # interpret-mode megakernel backend)
-        u_pred = jax.lax.cond(jnp.any(warm), predict,
-                              lambda: jnp.zeros_like(bp.f))
-        use_cronet = (warm & (state.err < error_threshold)
-                      & (state.it % verify_every != 0))
-        need_fea = ~use_cronet
+        with jax.named_scope("cronet_forward"):
+            u_pred = jax.lax.cond(jnp.any(warm), predict,
+                                  lambda: jnp.zeros_like(bp.f))
+        with jax.named_scope("gate"):
+            use_cronet = (warm & (state.err < error_threshold)
+                          & (state.it % verify_every != 0))
+            need_fea = ~use_cronet
 
         # the masked CG reports per-slot iteration counts and breakdown
         # flags alongside U; carrying them through the state (zeros when
         # no slot needed FEA) costs nothing on-device and gives the
         # serving engine the CG-fallback budget per request. Each solve
         # starts from zero, never from state (solve_b docstring: why).
-        u_fea, cg_its, cg_broke = jax.lax.cond(
-            jnp.any(need_fea),
-            lambda: fea2d.solve_b(bp, state.x, need=need_fea,
-                                  backend=fea_backend),
-            lambda: (jnp.zeros_like(bp.f), jnp.zeros_like(state.cg_iters),
-                     jnp.zeros_like(need_fea)))
+        with jax.named_scope("cg_solve"):
+            u_fea, cg_its, cg_broke = jax.lax.cond(
+                jnp.any(need_fea),
+                lambda: fea2d.solve_b(bp, state.x, need=need_fea,
+                                      backend=fea_backend),
+                lambda: (jnp.zeros_like(bp.f),
+                         jnp.zeros_like(state.cg_iters),
+                         jnp.zeros_like(need_fea)))
 
         # batch-invariant norms: err is COMPARED against the gate threshold,
         # so it must be bitwise-identical at any batch width
-        un = fea2d.tree_norm(u_fea)
-        err_new = fea2d.tree_norm(u_pred - u_fea) / jnp.maximum(un, 1e-30)
-        err = jnp.where(need_fea & warm, err_new, state.err)
-        u = jnp.where(use_cronet[:, None], u_pred, u_fea)
+        with jax.named_scope("gate"):
+            un = fea2d.tree_norm(u_fea)
+            err_new = (fea2d.tree_norm(u_pred - u_fea)
+                       / jnp.maximum(un, 1e-30))
+            err = jnp.where(need_fea & warm, err_new, state.err)
+            u = jnp.where(use_cronet[:, None], u_pred, u_fea)
 
-        c, dc = fea2d.compliance_and_sens_b(bp, state.x, u)
-        # elem_mask=None is an EMPTY pytree subtree, so this branches at
-        # trace time — the unmasked path lowers to exactly the pre-ladder
-        # graph (bitwise contract with historical runs)
-        if bp.elem_mask is None:
-            dc_f = filt_b(state.x, dc)
-        else:
-            dc_f = filt_mask_b(state.x, dc, bp.elem_mask)
-        hist = jnp.roll(state.hist, -1, axis=1).at[:, -1].set(state.x)
-        if bp.elem_mask is None:
-            dv = jnp.ones_like(state.x) / (cfg.nelx * cfg.nely)
-            x = simp.oc_update_b(state.x, dc_f, dv[0], bp.volfrac)
-        else:
-            # the mean-over-ACTIVE-elements volume constraint has uniform
-            # gradient 1/active_count, which differs per slot under
-            # shape-class padding — a flat 1/(nelx*nely) would hand the
-            # bisection the padded mesh's gradient and shift the update
-            # away from what a dedicated (unpadded) engine computes
-            active = jnp.maximum(
-                fea2d.tree_sum(bp.elem_mask.reshape(state.x.shape[0], -1)),
-                1.0)
-            dv = jnp.ones_like(state.x) / active[:, None, None]
-            x = simp.oc_update_b(state.x, dc_f, dv, bp.volfrac,
-                                 mask=bp.elem_mask)
+        with jax.named_scope("sens_filter_oc"):
+            c, dc = fea2d.compliance_and_sens_b(bp, state.x, u)
+            # elem_mask=None is an EMPTY pytree subtree, so this branches at
+            # trace time — the unmasked path lowers to exactly the pre-ladder
+            # graph (bitwise contract with historical runs)
+            if bp.elem_mask is None:
+                dc_f = filt_b(state.x, dc)
+            else:
+                dc_f = filt_mask_b(state.x, dc, bp.elem_mask)
+            hist = jnp.roll(state.hist, -1, axis=1).at[:, -1].set(state.x)
+            if bp.elem_mask is None:
+                dv = jnp.ones_like(state.x) / (cfg.nelx * cfg.nely)
+                x = simp.oc_update_b(state.x, dc_f, dv[0], bp.volfrac)
+            else:
+                # the mean-over-ACTIVE-elements volume constraint has uniform
+                # gradient 1/active_count, which differs per slot under
+                # shape-class padding — a flat 1/(nelx*nely) would hand the
+                # bisection the padded mesh's gradient and shift the update
+                # away from what a dedicated (unpadded) engine computes
+                active = jnp.maximum(fea2d.tree_sum(
+                    bp.elem_mask.reshape(state.x.shape[0], -1)), 1.0)
+                dv = jnp.ones_like(state.x) / active[:, None, None]
+                x = simp.oc_update_b(state.x, dc_f, dv, bp.volfrac,
+                                     mask=bp.elem_mask)
         return HybridState(
             x=x, hist=hist, it=state.it + 1, err=err,
             n_cronet=state.n_cronet + use_cronet.astype(jnp.int32),

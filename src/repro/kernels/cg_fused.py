@@ -177,6 +177,7 @@ def _make_solve(B: int, nely: int, lanes: int, tol: float, max_iter: int,
                    jax.ShapeDtypeStruct((B, 1), jnp.int32),    # its
                    jax.ShapeDtypeStruct((B, 1), jnp.int32)],   # broke
         interpret=interpret,
+        name="cg_fused",
     )
 
 

@@ -281,6 +281,7 @@ def cronet_fused(cfg: CRONetConfig, params: Dict, load_vol: jax.Array,
         scratch_shapes=[pltpu.VMEM((cfg.t_depth, cfg.t_c2, trunk.lanes),
                                    _F32)],                 # trunk L3 stage
         interpret=resolve_interpret(interpret),
+        name="cronet_fused",
     )(*batched, *weights)
     out = out[:, 0]
     return out[0] if squeeze else out

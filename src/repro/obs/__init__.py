@@ -1,11 +1,14 @@
-"""repro.obs — zero-dependency observability for the serving stack.
+"""repro.obs — observability for the serving stack (stdlib, numpy and,
+for profiler spans, ``jax.profiler``).
 
 Three layers, one data path:
 
   * ``trace`` — per-request ``Trace`` span timelines (queued → compute
     → parked cycles → completion) with per-tick rings and the
     CRONet-accepted vs CG-fallback split, sampled via ``trace_every=N``
-    on the engine/gateway and assembled lock-free on the tick path.
+    on the engine/gateway and assembled lock-free on the tick path; and
+    ``Phase``, a named host phase of a loop written into the JAX
+    profiler's trace and summed for a metrics counter.
   * ``metrics`` — process-wide ``MetricsRegistry`` of counters, gauges
     and fixed-exponential-bucket histograms (no per-observation
     allocation); every serving layer records into ``default_registry()``
@@ -24,12 +27,12 @@ from repro.obs.export import TelemetrySnapshotter, read_snapshots
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                default_registry, exponential_buckets,
                                set_default_registry)
-from repro.obs.trace import Span, Trace
+from repro.obs.trace import Phase, Span, Trace
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "default_registry", "set_default_registry", "exponential_buckets",
-    "Span", "Trace",
+    "Phase", "Span", "Trace",
     "TelemetrySnapshotter", "read_snapshots",
     "render", "watch",
 ]

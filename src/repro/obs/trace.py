@@ -25,6 +25,10 @@ rung width, slot iteration) at dispatch, and the engine's sync points
 fill in the CRONet-accepted vs CG-fallback split with per-window
 iteration counts (device counters are only READ at boundaries the
 engine already synchronizes; tracing adds no extra device work).
+
+``Phase`` is the other half: a named host phase of a loop (the engine's
+tick phases), written into the JAX profiler's trace as a span and summed
+into a plain float slot that the loop flushes into a metrics counter.
 """
 from __future__ import annotations
 
@@ -32,12 +36,47 @@ import collections
 import time
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Phase", "Span", "Trace"]
 
 # span kinds, in canonical timeline order
 QUEUED = "queued"
 COMPUTE = "compute"
 PARKED = "parked"
+
+
+class Phase:
+    """A named host phase of a loop, reusable as a context manager.
+
+    Each ``with`` block is a ``jax.profiler.TraceAnnotation(name)`` span in
+    the profiler's trace (a no-op while no profiler runs) and adds its
+    ``time.perf_counter`` duration to ``acc[slot]``. Build one per (thread,
+    phase) up front: entering takes no lock and looks up no label (the
+    profiler's span object is its only allocation), and the only writer
+    of ``acc`` is the thread that owns it, which flushes the slots into a
+    metrics counter where it likes. Not re-entrant."""
+
+    __slots__ = ("name", "acc", "slot", "_t0", "_span")
+
+    def __init__(self, name: str, acc: List[float], slot: int):
+        self.name = name
+        self.acc = acc
+        self.slot = slot
+        self._t0 = 0.0
+        self._span = None
+
+    def __enter__(self):
+        self._span = TraceAnnotation(self.name)
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.acc[self.slot] += time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._span = None
+        return False
 
 
 class Span:

@@ -84,7 +84,18 @@ from repro.serve.types import (EngineClosed, EngineState, TopoFuture,
                                TopoRequest, pool_stats)
 
 __all__ = ["TopoRequest", "TopoFuture", "TopoServingEngine", "auto_shards",
-           "shard_devices", "engine_from_spec"]
+           "shard_devices", "engine_from_spec", "PHASES"]
+
+# The phases of a shard's tick, in loop order. Each is a profiler span
+# ``topo.<phase>`` and a label of ``topo_host_seconds_total``; together
+# they tile the loop. sync: waits on the device; harvest: finished lanes'
+# reads, resolve and metrics; admit: scheduler lock, EDF pops, preemption
+# decision; park: a preemption's park and re-queue; rung: ladder width
+# changes with their lane moves; seed: lane state seeds and resets;
+# upload: slot constants to the device; dispatch: the compiled step and
+# the per-lane bookkeeping after it; wait: idle, no lane occupied.
+PHASES = ("sync", "harvest", "admit", "park", "rung", "seed", "upload",
+          "dispatch", "wait")
 
 
 @dataclasses.dataclass
@@ -191,9 +202,14 @@ class _Shard:
         self.load_vol = None
         self.state = None
         self.steps = 0              # dispatched this activation
+        self.steps_flushed = 0      # of which in topo_steps_total
         self.busy_t0: Optional[float] = None   # sync-point timing window
         self.steps_in_window = 0
-        self.trace_sync_n = 0       # traced sync boundaries seen (throttle)
+        # host seconds of the tick loop by phase since the last flush into
+        # topo_host_seconds_total; one reusable span per phase
+        self.host_s = [0.0] * len(PHASES)
+        self.phases = tuple(obs_trace.Phase("topo." + name, self.host_s, i)
+                            for i, name in enumerate(PHASES))
 
     def activate(self):
         """Fresh idle state for a (re)started tick loop."""
@@ -208,6 +224,7 @@ class _Shard:
         self.slot_adm = [None] * L
         self.slot_iters = [0] * L
         self.steps = 0
+        self.steps_flushed = 0
         self.busy_t0 = None
         self.steps_in_window = 0
         # params are re-put per activation: a swap_params() between
@@ -486,6 +503,12 @@ class TopoServingEngine:
         self._m_inflight = m.gauge(
             "topo_inflight",
             "accepted-but-unresolved requests per engine mesh")
+        self._m_host = m.counter(
+            "topo_host_seconds_total",
+            "host seconds of the shard tick loops by (mesh, phase)")
+        self._m_steps = m.counter(
+            "topo_steps_total",
+            "compiled steps dispatched by the shard tick loops, by mesh")
         self.preemptions = 0        # engine lifetime eviction count
         self._steps_base = 0        # steps from finished activations
         self.last_run_steps = 0     # most recent run() only
@@ -758,30 +781,19 @@ class TopoServingEngine:
             adm.req.trace.window(t, d_it, d_cro, d_fea, d_cg)
         adm.tr_base = (it, cro, fea, cg)
 
-    def _trace_sync(self, shard: _Shard, every: int = 8):
-        """Flush window deltas for traced live lanes at a boundary the
-        tick loop ALREADY synchronized — one batched (B,)-host read, and
-        only when a traced lane is live, so the untraced hot path runs
-        the exact same code it did before tracing existed. Throttled to
-        every ``every``-th traced sync boundary: the readback is tiny
-        but not free, and park/harvest flush the SAME counters exactly
-        at the span boundaries, so mid-span windows are a coarse
-        progress signal, not the source of truth."""
-        lanes = [i for i in range(shard.width)
-                 if shard.slot_adm[i] is not None
-                 and shard.slot_adm[i].req.trace is not None]
-        if not lanes:
-            return
-        shard.trace_sync_n += 1
-        if shard.trace_sync_n % every:
-            return
-        it, cro, fea, cg = jax.device_get(
-            (shard.state.it, shard.state.n_cronet,
-             shard.state.n_fea, shard.state.cg_iters))
-        t = time.monotonic()
-        for i in lanes:
-            self._trace_flush(shard.slot_adm[i], t, int(it[i]),
-                              int(cro[i]), int(fea[i]), int(cg[i]))
+    def _flush_host(self, shard: _Shard):
+        """Move the shard's per-phase host seconds into
+        ``topo_host_seconds_total`` and its steps since the last flush
+        into ``topo_steps_total`` (tick-loop thread only)."""
+        acc = shard.host_s
+        for i, name in enumerate(PHASES):
+            if acc[i]:
+                self._m_host.inc(acc[i], mesh=self._mesh_label, phase=name)
+                acc[i] = 0.0
+        if shard.steps > shard.steps_flushed:
+            self._m_steps.inc(shard.steps - shard.steps_flushed,
+                              mesh=self._mesh_label)
+            shard.steps_flushed = shard.steps
 
     def _harvest_lane(self, shard: _Shard, lane: int, now: float):
         """Pull a finished lane's result (device sync) + resolve."""
@@ -859,153 +871,19 @@ class TopoServingEngine:
         shard.fill(lane, adm)
 
     def _shard_loop(self, shard: _Shard):
-        """One shard's tick loop: harvest finished lanes, drain admissions
-        (EDF pops + at most one slack-safe preemption), pick the ladder
-        rung for the live occupancy (compact + resize when it changed),
-        seed the lanes touched this tick, dispatch the next compiled
-        step. No device sync except at harvest and park."""
+        """One shard's tick loop. Each tick is a ``topo.tick`` step span in
+        the profiler's trace, tiled by its ``PHASES`` spans; their host
+        seconds go into ``topo_host_seconds_total``, and the steps into
+        ``topo_steps_total``, every second dispatch and when the loop
+        exits."""
         sched = self._sched
-        L = self.shard_width
         try:
             shard.activate()
             while True:
-                now = time.monotonic()
-                # -- harvest (single-writer lane bookkeeping, syncs device)
-                harvested = False
-                for i in range(L):
-                    adm = shard.slot_adm[i]
-                    if adm is not None and shard.slot_iters[i] >= adm.req.n_iter:
-                        self._harvest_lane(shard, i, now)
-                        harvested = True
-                # -- admissions: atomic vs concurrent submit(). fill()
-                # writes host constants only; device seeding waits until
-                # the tick's rung is settled (seeds list below)
-                dirty = harvested
-                seeds: List[int] = []     # admitted lanes awaiting device seed
-                cleared: List[int] = []   # harvested lanes left empty
-                cap = shard.cap
-                with sched.cond:
-                    occupied_n = sum(a is not None for a in shard.slot_adm)
-                    for i in range(L):
-                        if shard.slot_adm[i] is not None:
-                            continue
-                        entry = sched.pop() if occupied_n < cap else None
-                        if entry is None:
-                            if harvested:
-                                shard.fill(i, None)  # clear stale load
-                                cleared.append(i)
-                            continue
-                        self._admit_lane(shard, i, entry.payload, now)
-                        seeds.append(i)
-                        occupied_n += 1
-                        dirty = True
-                    # preemption: queue head about to miss, no free lane.
-                    # Decide and pop the head under the lock; the actual
-                    # park (a device sync) happens after release so other
-                    # shards and submit() are not stalled behind it.
-                    # Popping the head BEFORE re-queueing the victim also
-                    # matters: a long-waiting deadline-less victim can
-                    # outrank the head (starvation horizon), and popping
-                    # after the push would hand the lane straight back to
-                    # the evictee. Preemption stays keyed to a TRULY full
-                    # shard: a rung cap below full width pauses admission
-                    # but never evicts (the cap is elasticity, not urgency).
-                    victim = preempt_entry = None
-                    head = sched.peek() if self.preempt else None
-                    if head is not None and all(a is not None
-                                                for a in shard.slot_adm):
-                        views = [
-                            None if a is None else SlotView(
-                                deadline=(a.req.deadline if a.req.deadline
-                                          is not None else INF),
-                                iters_left=a.req.n_iter - shard.slot_iters[i],
-                                preemptible=i not in seeds)
-                            for i, a in enumerate(shard.slot_adm)]
-                        victim = preempt_victim(
-                            head.deadline, head.payload.iters_left,
-                            views, now, self._estimate())
-                        if victim is not None:
-                            preempt_entry = sched.pop()
-                    occupied = any(a is not None for a in shard.slot_adm)
-                    if not occupied and preempt_entry is None:
-                        if self._stopping and len(sched._heap) == 0:
-                            break
-                        shard.busy_t0 = None
-                        shard.steps_in_window = 0
-                        sched.cond.wait(timeout=0.1)
-                        continue
-                if preempt_entry is not None:
-                    parked = shard.park(victim)   # device sync, lock-free
-                    self.preemptions += 1
-                    self._m_preempt.inc(mesh=self._mesh_label)
-                    if parked.req.trace is not None:
-                        # the parked snapshot is already on host: flush
-                        # the window up to the park and open the parked
-                        # span (closed again at re-admission)
-                        t_park = time.monotonic()
-                        self._trace_flush(
-                            parked, t_park, int(parked.parked.it),
-                            int(parked.parked.n_cronet),
-                            int(parked.parked.n_fea),
-                            int(parked.parked.cg_iters))
-                        parked.req.trace.begin(obs_trace.PARKED, t=t_park,
-                                               iters_done=parked.iters_done)
-                    sched.push(parked, parked.req.deadline, now,
-                               seq=parked.seq,
-                               eff_deadline=parked.eff_deadline,
-                               priority=parked.req.priority)
-                    self._admit_lane(shard, victim, preempt_entry.payload,
-                                     now)
-                    seeds.append(victim)
-                    dirty = True
-                # -- ladder rung: smallest compiled width >= occupancy.
-                # Live lanes above the new width migrate down via exact
-                # lane copies BEFORE the state is sliced, so a rung
-                # shrink never touches a trajectory; seeds (admitted this
-                # tick, no device state yet) are relabeled in place.
-                occ = sum(a is not None for a in shard.slot_adm)
-                if shard._set_width(rung_for(occ, shard.rungs), seeds):
-                    dirty = True
-                for i in seeds:
-                    shard.seed(i)
-                for i in cleared:     # reset harvested-but-idle lane state
-                    # (unless a rung shrink sliced it off or compacted a
-                    # live lane into it)
-                    if i < shard.width and shard.slot_adm[i] is None:
-                        shard.seed(i)
-                if dirty:
-                    shard._upload()
-                # -- tick: one compiled step, admissions drain before the
-                # next one; dispatch is async
-                if shard.busy_t0 is None:
-                    shard.busy_t0 = time.monotonic()
-                shard.state = self.step(shard.params, shard.bp,
-                                        shard.load_vol, shard.state)
-                shard.steps += 1
-                shard.rung_steps[shard.width] += 1
-                shard.steps_in_window += 1
-                t_tick = None    # stamped lazily, only if a lane is traced
-                for i in range(L):
-                    adm_i = shard.slot_adm[i]
-                    if adm_i is not None:
-                        shard.slot_iters[i] += 1
-                        if adm_i.req.trace is not None:
-                            if t_tick is None:
-                                t_tick = time.monotonic()
-                            adm_i.req.trace.tick(t_tick, shard.width,
-                                                 shard.slot_iters[i])
-                # bound the dispatch-ahead depth: unchecked, the host can
-                # queue the whole burst to the next completion (~shard
-                # width x n_iter steps) before the device catches up, and
-                # a request admitted "immediately" would start computing
-                # behind that backlog — blowing exactly the tight
-                # deadlines the scheduler exists to protect. Waiting on
-                # the current frontier every 2 dispatches keeps admission-
-                # to-silicon latency <= 2 ticks at negligible pipeline
-                # cost (host-side bookkeeping is microseconds per tick).
-                if shard.steps_in_window % 2 == 0:
-                    jax.block_until_ready(shard.state.it)
-                    self._trace_sync(shard)
+                with jax.profiler.StepTraceAnnotation("topo.tick",
+                                                      step_num=shard.steps):
+                    if not self._tick(shard):
+                        break
         except BaseException as exc:  # fail every waiter, don't hang
             with sched.cond:
                 self._failure = exc
@@ -1024,9 +902,176 @@ class TopoServingEngine:
                 self._sched.cond.notify_all()
             raise
         finally:
+            self._flush_host(shard)
             with self._steps_lock:
                 self._steps_base += shard.steps
                 shard.steps = 0
+
+    def _tick(self, shard: _Shard) -> bool:
+        """One tick: harvest finished lanes, drain admissions (EDF pops +
+        at most one slack-safe preemption), pick the ladder rung for the
+        live occupancy (compact + resize when it changed), seed the lanes
+        touched this tick, dispatch the next compiled step; or, with no
+        lane occupied, wait for work. No device sync except before a
+        harvest, at park and every second dispatch. Returns False when
+        the loop is to exit."""
+        sched = self._sched
+        L = self.shard_width
+        (sync, harvest, admit, park, rung, seed, upload, dispatch,
+         wait) = shard.phases
+        now = time.monotonic()
+        done = [i for i, adm in enumerate(shard.slot_adm)
+                if adm is not None and shard.slot_iters[i] >= adm.req.n_iter]
+        if done:
+            # the lane reads would wait for the device anyway: waiting
+            # here first leaves the harvest phase host work and transfers
+            with sync:
+                jax.block_until_ready(shard.state)
+            with harvest:
+                for i in done:
+                    self._harvest_lane(shard, i, now)
+        harvested = bool(done)
+        # -- admissions: atomic vs concurrent submit(). fill() writes host
+        # constants only; device seeding waits until the tick's rung is
+        # settled (seeds list below)
+        dirty = harvested
+        seeds: List[int] = []     # admitted lanes awaiting device seed
+        cleared: List[int] = []   # harvested lanes left empty
+        cap = shard.cap
+        with admit, sched.cond:
+            occupied_n = sum(a is not None for a in shard.slot_adm)
+            for i in range(L):
+                if shard.slot_adm[i] is not None:
+                    continue
+                entry = sched.pop() if occupied_n < cap else None
+                if entry is None:
+                    if harvested:
+                        shard.fill(i, None)  # clear stale load
+                        cleared.append(i)
+                    continue
+                self._admit_lane(shard, i, entry.payload, now)
+                seeds.append(i)
+                occupied_n += 1
+                dirty = True
+            # preemption: queue head about to miss, no free lane. Decide
+            # and pop the head under the lock; the actual park (a device
+            # sync) happens after release so other shards and submit()
+            # are not stalled behind it. Popping the head BEFORE
+            # re-queueing the victim also matters: a long-waiting
+            # deadline-less victim can outrank the head (starvation
+            # horizon), and popping after the push would hand the lane
+            # straight back to the evictee. Preemption stays keyed to a
+            # TRULY full shard: a rung cap below full width pauses
+            # admission but never evicts (the cap is elasticity, not
+            # urgency).
+            victim = preempt_entry = None
+            head = sched.peek() if self.preempt else None
+            if head is not None and all(a is not None
+                                        for a in shard.slot_adm):
+                views = [
+                    None if a is None else SlotView(
+                        deadline=(a.req.deadline if a.req.deadline
+                                  is not None else INF),
+                        iters_left=a.req.n_iter - shard.slot_iters[i],
+                        preemptible=i not in seeds)
+                    for i, a in enumerate(shard.slot_adm)]
+                victim = preempt_victim(
+                    head.deadline, head.payload.iters_left,
+                    views, now, self._estimate())
+                if victim is not None:
+                    preempt_entry = sched.pop()
+            idle = (preempt_entry is None
+                    and all(a is None for a in shard.slot_adm))
+            if idle:
+                if self._stopping and len(sched._heap) == 0:
+                    return False
+                shard.busy_t0 = None
+                shard.steps_in_window = 0
+        if idle:
+            with wait, sched.cond:
+                # checked again under the lock: a submit() since the
+                # admit phase has already notified
+                if not sched._heap and not self._stopping:
+                    sched.cond.wait(timeout=0.1)
+            return True
+        if preempt_entry is not None:
+            with park:
+                parked = shard.park(victim)   # device sync, lock-free
+                self.preemptions += 1
+                self._m_preempt.inc(mesh=self._mesh_label)
+                if parked.req.trace is not None:
+                    # the parked snapshot is already on host: flush the
+                    # window up to the park and open the parked span
+                    # (closed again at re-admission)
+                    t_park = time.monotonic()
+                    self._trace_flush(
+                        parked, t_park, int(parked.parked.it),
+                        int(parked.parked.n_cronet),
+                        int(parked.parked.n_fea),
+                        int(parked.parked.cg_iters))
+                    parked.req.trace.begin(obs_trace.PARKED, t=t_park,
+                                           iters_done=parked.iters_done)
+                sched.push(parked, parked.req.deadline, now,
+                           seq=parked.seq, eff_deadline=parked.eff_deadline,
+                           priority=parked.req.priority)
+                self._admit_lane(shard, victim, preempt_entry.payload, now)
+                seeds.append(victim)
+                dirty = True
+        # -- ladder rung: smallest compiled width >= occupancy. Live lanes
+        # above the new width migrate down via exact lane copies BEFORE
+        # the state is sliced, so a rung shrink never touches a
+        # trajectory; seeds (admitted this tick, no device state yet) are
+        # relabeled in place.
+        width = rung_for(sum(a is not None for a in shard.slot_adm),
+                         shard.rungs)
+        if width != shard.width:
+            with rung:
+                shard._set_width(width, seeds)
+                dirty = True
+        # reset harvested-but-idle lane state, unless a rung shrink sliced
+        # it off or compacted a live lane into it
+        cleared = [i for i in cleared
+                   if i < shard.width and shard.slot_adm[i] is None]
+        if seeds or cleared:
+            with seed:
+                for i in seeds + cleared:
+                    shard.seed(i)
+        if dirty:
+            with upload:
+                shard._upload()
+        # -- tick: one compiled step, admissions drain before the next
+        # one; dispatch is async
+        with dispatch:
+            if shard.busy_t0 is None:
+                shard.busy_t0 = time.monotonic()
+            shard.state = self.step(shard.params, shard.bp,
+                                    shard.load_vol, shard.state)
+            shard.steps += 1
+            shard.rung_steps[shard.width] += 1
+            shard.steps_in_window += 1
+            t_tick = None    # stamped lazily, only if a lane is traced
+            for i in range(L):
+                adm_i = shard.slot_adm[i]
+                if adm_i is not None:
+                    shard.slot_iters[i] += 1
+                    if adm_i.req.trace is not None:
+                        if t_tick is None:
+                            t_tick = time.monotonic()
+                        adm_i.req.trace.tick(t_tick, shard.width,
+                                             shard.slot_iters[i])
+        # bound the dispatch-ahead depth: unchecked, the host can queue the
+        # whole burst to the next completion (~shard width x n_iter steps)
+        # before the device catches up, and a request admitted
+        # "immediately" would start computing behind that backlog —
+        # blowing exactly the tight deadlines the scheduler exists to
+        # protect. Waiting on the current frontier every 2 dispatches
+        # keeps admission-to-silicon latency <= 2 ticks at negligible
+        # pipeline cost (host-side bookkeeping is microseconds per tick).
+        if shard.steps_in_window % 2 == 0:
+            with sync:
+                jax.block_until_ready(shard.state.it)
+            self._flush_host(shard)
+        return True
 
     # -------------------------------------------------------------- shim
 

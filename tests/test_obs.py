@@ -455,3 +455,80 @@ def test_tracing_bitwise_invisible_across_canary_routing(trained,
     assert events and all(e.t_mono > 0.0 for e in events)
     assert [e.t_mono for e in events] == sorted(e.t_mono for e in events)
     assert any(e.kind == "canary-start" for e in events)
+
+
+def test_tick_phases_tile_the_loop_and_the_profiler_is_invisible(
+        trained, tmp_path):
+    """An engine on the fused backends (interpret mode) with a ladder and a
+    forced preemption, so that every tick phase runs: each phase's host
+    seconds reach ``topo_host_seconds_total``, the phases sum to the tick
+    loop's wall time within 5%, ``topo_steps_total`` counts every
+    compiled step, each phase is a span in a profiler trace,
+    and densities are bitwise-equal with the profiler on."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.serve import TopoRequest, TopoServingEngine
+    from repro.serve.topo_service import PHASES
+
+    cfg, params = trained
+    probs = _problems(5)
+
+    def serve():
+        reg = MetricsRegistry()
+        eng = TopoServingEngine(cfg, params, U_SCALE, slots=4, ladder=(2, 4),
+                                precision="fp32", tick_time_s=10.0,
+                                backend="megakernel", fea_backend="fused",
+                                metrics=reg)
+        ticks = []
+        tick = eng._tick
+
+        def timed_tick(shard):
+            t0 = time.perf_counter()
+            try:
+                return tick(shard)
+            finally:
+                ticks.append((t0, time.perf_counter()))
+
+        eng._tick = timed_tick
+        futs = [eng.submit(TopoRequest(uid=k, problem=probs[k], n_iter=40))
+                for k in range(4)]
+        t0 = time.time()
+        while any(a is None for a in eng._shards[0].slot_adm):
+            assert time.time() - t0 < 120, "occupants never admitted"
+            time.sleep(0.002)
+        futs.append(eng.submit(TopoRequest(uid=9, problem=probs[4],
+                                           n_iter=3), deadline_s=35.0))
+        done = [f.result(timeout=600) for f in futs]
+        time.sleep(0.3)          # idle ticks: the wait phase
+        eng.shutdown()
+        host = reg.counter("topo_host_seconds_total")
+        phases = {p: host.value(mesh="12x4", phase=p) for p in PHASES}
+        steps = reg.counter("topo_steps_total").value(mesh="12x4")
+        assert steps == eng.total_steps > 0, (steps, eng.total_steps)
+        return done, phases, ticks[-1][1] - ticks[0][0]
+
+    plain, phases, loop_s = serve()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced, _, _ = serve()
+    finally:
+        jax.profiler.stop_trace()
+
+    assert sum(r.preemptions for r in plain) >= 1, "preemption never fired"
+    assert all(s > 0 for s in phases.values()), phases
+    assert abs(sum(phases.values()) - loop_s) <= 0.05 * loop_s, \
+        (phases, loop_s)
+    for a, b in zip(plain, traced):
+        np.testing.assert_array_equal(a.density, b.density,
+                                      err_msg=f"uid {a.uid}")
+    names = set()
+    for path in glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                names.update(e.name for e in line.events
+                             if e.name.startswith("topo."))
+    assert names >= {"topo.tick"} | {"topo." + p for p in PHASES}, names

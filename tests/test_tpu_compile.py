@@ -16,6 +16,7 @@ explicit ``interpret=False``.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,3 +124,29 @@ def test_default_hybrid_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def test_fused_hybrid_step_names_its_scopes_and_kernels(one_chip,
+                                                        monkeypatch):
+    """The serving tick on the deployed backends (megakernel forward, fused
+    CG) carries the four named scopes in its op metadata and both kernel
+    names on their custom calls, so that a profiler trace can attribute
+    device time to them."""
+    from repro.kernels import cg_fused as cg_mod
+
+    monkeypatch.setattr(cronet_pipeline, "resolve_interpret", lambda _: False)
+    monkeypatch.setattr(cg_mod, "resolve_interpret", lambda _: False)
+    cfg = get_cronet_config("small")
+    bp = _batch(cfg, 2)
+    step = hybrid.make_hybrid_step(cfg, 377.622, precision="fp32",
+                                   backend="megakernel", fea_backend="fused")
+    text = step.lower(
+        _params(dataclasses.replace(cfg, dtype="float32"), one_chip),
+        _abstract(bp, one_chip),
+        _abstract(fea2d.load_volume_b(bp), one_chip),
+        _abstract(hybrid.init_state(cfg, bp), one_chip)).compile().as_text()
+    for scope in ("cronet_forward", "gate", "cg_solve", "sens_filter_oc"):
+        assert f'op_name="jit(step)/{scope}/' in text, scope
+    for kernel in ("cronet_fused", "cg_fused"):
+        assert re.search(rf"%{kernel}(\.\d+)? = .*custom_call_target="
+                         r'"tpu_custom_call"', text), kernel
