@@ -111,6 +111,30 @@ def reset_slot(cfg: CRONetConfig, state: HybridState, i: int,
     )
 
 
+def reset_lanes(state: HybridState, reset, volfrac,
+                elem_mask=None) -> HybridState:
+    """Re-initialize every lane flagged in ``reset`` ((B,) bool) in one
+    pass, leaving the others untouched: lane for lane the values
+    ``reset_slot`` writes, as a ``jnp.where`` over the flags, so it traces
+    into a single program. ``volfrac`` is (B,); ``elem_mask`` (B, nely,
+    nelx) zeroes the passive shape-class border."""
+    x0 = jnp.broadcast_to(volfrac[:, None, None], state.x.shape)
+    if elem_mask is not None:
+        x0 = x0 * elem_mask
+
+    def where(leaf, fresh):
+        flags = reset.reshape(reset.shape + (1,) * (leaf.ndim - 1))
+        return jnp.where(flags, jnp.asarray(fresh, leaf.dtype), leaf)
+
+    return HybridState(
+        x=where(state.x, x0), hist=where(state.hist, 0.0),
+        it=where(state.it, 0), err=where(state.err, jnp.inf),
+        n_cronet=where(state.n_cronet, 0), n_fea=where(state.n_fea, 0),
+        compliance=where(state.compliance, 0.0),
+        cg_iters=where(state.cg_iters, 0),
+        cg_breakdowns=where(state.cg_breakdowns, 0))
+
+
 def park_slot(state: HybridState, i: int) -> HybridState:
     """Gather lane i to host numpy (preemption parking).
 

@@ -91,8 +91,9 @@ __all__ = ["TopoRequest", "TopoFuture", "TopoServingEngine", "auto_shards",
 # they tile the loop. sync: waits on the device; harvest: finished lanes'
 # reads, resolve and metrics; admit: scheduler lock, EDF pops, preemption
 # decision; park: a preemption's park and re-queue; rung: ladder width
-# changes with their lane moves; seed: lane state seeds and resets;
-# upload: slot constants to the device; dispatch: the compiled step and
+# changes with their lane moves; seed: parked-lane restores and the
+# reset mask; upload: the lane-write program (slot constants to the
+# device, TrunkNet inputs, lane resets); dispatch: the compiled step and
 # the per-lane bookkeeping after it; wait: idle, no lane occupied.
 PHASES = ("sync", "harvest", "admit", "park", "rung", "seed", "upload",
           "dispatch", "wait")
@@ -131,6 +132,50 @@ def _mesh_template(nelx: int, nely: int):
     init, not a cold start."""
     template = fea2d.mbb_problem(nelx, nely)
     return template.edof, template.KE, template.penal, template.e_min
+
+
+def _pack_lanes(f, free, fixed_x, volfrac, elem) -> np.ndarray:
+    """One host row per lane, ``[f | free | fixed_x | volfrac | elem]``
+    (``elem`` only on shape-padded engines): the lane write's single
+    transfer. A fresh buffer, so later host writes cannot reach it."""
+    cols = [f, free, fixed_x, volfrac[:, None]]
+    if elem is not None:
+        cols.append(elem.reshape(len(elem), -1))
+    return np.concatenate(cols, axis=1)
+
+
+@functools.lru_cache(maxsize=64)
+def _lane_write_program(nelx: int, nely: int, masked: bool):
+    """The compiled lane write of a dirty tick, shared by every engine of
+    one mesh and mask variant (jit traces once per rung width):
+
+        write(state, lanes, reset) -> (f, free_mask, fixed_x_mask,
+                                       volfrac, elem_mask), load_vol, state
+
+    ``lanes`` is ``_pack_lanes`` output for lanes ``[:width]``; ``reset``
+    ((width,) bool) flags the lanes to re-seed. It unpacks the slot
+    constants, computes the TrunkNet inputs as ``fea2d.load_volume_b``
+    does, and resets the flagged lanes of the donated state
+    (``hybrid.reset_lanes``): one dispatch and one host buffer in place
+    of an eager op per leaf and lane."""
+    ndof = 2 * (nelx + 1) * (nely + 1)
+    trace_count = [0]  # bumped per retrace, like make_hybrid_step's
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def write(state: hybrid.HybridState, lanes, reset):
+        trace_count[0] += 1
+        f, free, fixed_x = (lanes[:, k * ndof:(k + 1) * ndof]
+                            for k in range(3))
+        volfrac = lanes[:, 3 * ndof]
+        elem = (lanes[:, 3 * ndof + 1:].reshape(-1, nely, nelx)
+                if masked else None)
+        load_vol = jax.vmap(
+            lambda f, m: fea2d._load_volume(f, m, nelx, nely))(f, fixed_x)
+        state = hybrid.reset_lanes(state, reset, volfrac, elem)
+        return (f, free, fixed_x, volfrac, elem), load_vol, state
+
+    write.trace_count = trace_count
+    return write
 
 
 def auto_shards(slots: int, device_count: Optional[int] = None) -> int:
@@ -181,7 +226,7 @@ class _Shard:
         ndof = 2 * (cfg.nelx + 1) * (cfg.nely + 1)
         # empty slots carry f == 0 so the masked CG treats them as
         # converged in zero iterations. Host arrays stay FULL width L;
-        # _upload() slices [:width] for the current ladder rung.
+        # _upload() packs [:width] for the current ladder rung.
         self.f = np.zeros((L, ndof), np.float32)
         self.free = np.zeros((L, ndof), np.float32)
         self.fixed_x = np.zeros((L, ndof), np.float32)
@@ -198,6 +243,7 @@ class _Shard:
         self.rung_changes = 0
         self.migrations = 0          # device lane moves from rung shrinks
         self.params = None          # device copy, refreshed by activate()
+        self.mesh = None            # the BatchProblem's mesh leaves, on device
         self.bp = None
         self.load_vol = None
         self.state = None
@@ -230,6 +276,11 @@ class _Shard:
         # params are re-put per activation: a swap_params() between
         # activations (hot model swap) takes effect on the next start
         self.params = jax.device_put(e.params, self.device)
+        # the mesh leaves of every BatchProblem this activation uploads:
+        # put once, reused by each lane write
+        self.mesh = jax.device_put(
+            (e.cfg.nelx, e.cfg.nely, e._edof, e._KE, e._penal, e._e_min),
+            self.device)
         # precompile every ladder rung before serving traffic (no-op for
         # ladder=None engines and on restarts)
         e._warm_ladder(self.device, self.params)
@@ -252,18 +303,28 @@ class _Shard:
                 (e.cfg.nely, e.cfg.nelx), jnp.float32))
         return fea2d.stack_problems([idle] * width)
 
-    def _upload(self):
+    def _upload(self, reset: Optional[np.ndarray] = None):
+        """Write lanes ``[:width]`` to the device in one compiled program
+        (``_lane_write_program``): the slot constants as one packed
+        buffer, ``load_vol`` computed on the device, and the lanes
+        flagged in ``reset`` (``seed``'s mask; None flags none)
+        re-seeded in the donated state."""
         e = self.engine
         w = self.width
-        self.bp = jax.device_put(fea2d.BatchProblem(
-            nelx=e.cfg.nelx, nely=e.cfg.nely, edof=e._edof, KE=e._KE,
-            f=jnp.asarray(self.f[:w]), free_mask=jnp.asarray(self.free[:w]),
-            fixed_x_mask=jnp.asarray(self.fixed_x[:w]),
-            volfrac=jnp.asarray(self.volfrac[:w]),
-            penal=e._penal, e_min=e._e_min,
-            elem_mask=(jnp.asarray(self.elem[:w])
-                       if self.elem is not None else None)), self.device)
-        self.load_vol = fea2d.load_volume_b(self.bp)
+        if reset is None:
+            reset = np.zeros(w, bool)
+        lanes = _pack_lanes(self.f[:w], self.free[:w], self.fixed_x[:w],
+                            self.volfrac[:w],
+                            self.elem[:w] if self.elem is not None else None)
+        (f, free, fixed_x, volfrac, elem), self.load_vol, self.state = \
+            e._lane_write(self.state, lanes, reset)
+        nelx, nely, edof, KE, penal, e_min = self.mesh
+        self.bp = fea2d.BatchProblem(
+            nelx=nelx, nely=nely, edof=edof, KE=KE, f=f, free_mask=free,
+            fixed_x_mask=fixed_x, volfrac=volfrac, penal=penal, e_min=e_min,
+            elem_mask=elem)
+        e._m_writes.inc(mesh=e._mesh_label)
+        e._m_resets.inc(int(reset.sum()), mesh=e._mesh_label)
 
     def fill(self, lane: int, adm: Optional[_Admission]):
         """Write lane HOST constants + bookkeeping for an admission (or
@@ -289,21 +350,24 @@ class _Shard:
                                    if p.elem_mask is not None else 1.0)
         self.slot_adm[lane] = adm
 
-    def seed(self, lane: int):
-        """Seed lane device state: exact restore for a parked admission,
-        fresh reset otherwise (also used to clear harvested lanes)."""
-        adm = self.slot_adm[lane]
-        if adm is not None and adm.parked is not None:
-            self.state = hybrid.restore_slot(self.state, lane, adm.parked)
-            self.slot_iters[lane] = adm.iters_done
-            adm.parked = None
-        else:
-            mask = (jnp.asarray(self.elem[lane])
-                    if self.elem is not None and adm is not None else None)
-            self.state = hybrid.reset_slot(
-                self.engine.cfg, self.state, lane, float(self.volfrac[lane]),
-                mask)
-            self.slot_iters[lane] = 0
+    def seed(self, lanes: List[int]) -> np.ndarray:
+        """Seed the device state of ``lanes``: an exact restore for a
+        parked admission, here and now; a fresh reset otherwise (also for
+        harvested lanes left empty), flagged in the returned (width,)
+        mask for the tick's ``_upload``, which resets them all in its one
+        program."""
+        reset = np.zeros(self.width, bool)
+        for lane in lanes:
+            adm = self.slot_adm[lane]
+            if adm is not None and adm.parked is not None:
+                self.state = hybrid.restore_slot(self.state, lane,
+                                                 adm.parked)
+                self.slot_iters[lane] = adm.iters_done
+                adm.parked = None
+            else:
+                reset[lane] = True
+                self.slot_iters[lane] = 0
+        return reset
 
     def move_lane(self, src: int, dst: int, live: bool):
         """Relocate a lane's occupant to a lower index (rung-shrink
@@ -451,6 +515,8 @@ class TopoServingEngine:
         self.step = hybrid.make_hybrid_step(
             cfg, u_scale, error_threshold, verify_every, rmin, precision,
             backend, fea_backend)
+        self._lane_write = _lane_write_program(cfg.nelx, cfg.nely,
+                                               shape_padded)
         self.preempt = preempt
         self.tick_time_s = tick_time_s
         (self._edof, self._KE,
@@ -509,6 +575,13 @@ class TopoServingEngine:
         self._m_steps = m.counter(
             "topo_steps_total",
             "compiled steps dispatched by the shard tick loops, by mesh")
+        self._m_writes = m.counter(
+            "topo_lane_writes_total",
+            "compiled lane-write programs dispatched (dirty ticks and "
+            "activations), by mesh")
+        self._m_resets = m.counter(
+            "topo_lanes_reset_total",
+            "lanes re-seeded by the lane-write programs, by mesh")
         self.preemptions = 0        # engine lifetime eviction count
         self._steps_base = 0        # steps from finished activations
         self.last_run_steps = 0     # most recent run() only
@@ -659,19 +732,24 @@ class TopoServingEngine:
             # whose first use would otherwise compile INSIDE a serving
             # tick (a multi-hundred-ms latency spike on the first burst);
             # touch every rung pair and a lane move here instead
-            mask = (jnp.ones((self.cfg.nely, self.cfg.nelx), jnp.float32)
-                    if self.shape_padded else None)
+            ndof = 2 * (self.cfg.nelx + 1) * (self.cfg.nely + 1)
             for a in self._rungs:
                 for b in self._rungs:
                     if a != b:
                         jax.block_until_ready(
                             hybrid.resize_state(states[a], b).it)
-                # first reset/compaction at a fresh width compiles the
-                # eager lane ops; per-lane residuals after this are
-                # dispatch-only
-                jax.block_until_ready(hybrid.reset_slot(
-                    self.cfg, states[a], 0, 0.5, elem_mask=mask).x)
+                # first compaction at a fresh width compiles the eager
+                # lane ops; per-lane residuals after this are dispatch-only
                 jax.block_until_ready(hybrid.move_slot(states[a], 1, 0).it)
+                # the tick's lane write at this rung, in the engine's mask
+                # variant (it donates states[a], used no more)
+                elem = (np.ones((a, self.cfg.nely, self.cfg.nelx),
+                                np.float32) if self.shape_padded else None)
+                zeros = np.zeros((a, ndof), np.float32)
+                lanes = _pack_lanes(zeros, zeros, zeros,
+                                    np.full(a, 0.5, np.float32), elem)
+                jax.block_until_ready(self._lane_write(
+                    states[a], lanes, np.ones(a, bool))[2].x)
             self._warmed_devices.add(device)
 
     def set_target_slots(self, n: int) -> int:
@@ -1032,13 +1110,13 @@ class TopoServingEngine:
         # it off or compacted a live lane into it
         cleared = [i for i in cleared
                    if i < shard.width and shard.slot_adm[i] is None]
+        reset = None
         if seeds or cleared:
             with seed:
-                for i in seeds + cleared:
-                    shard.seed(i)
+                reset = shard.seed(seeds + cleared)
         if dirty:
             with upload:
-                shard._upload()
+                shard._upload(reset)
         # -- tick: one compiled step, admissions drain before the next
         # one; dispatch is async
         with dispatch:
