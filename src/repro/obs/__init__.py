@@ -8,7 +8,8 @@ Three layers, one data path:
     CRONet-accepted vs CG-fallback split, sampled via ``trace_every=N``
     on the engine/gateway and assembled lock-free on the tick path; and
     ``Phase``, a named host phase of a loop written into the JAX
-    profiler's trace and summed for a metrics counter.
+    profiler's trace and summed, as wall and CPU time, for metrics
+    counters.
   * ``metrics`` — process-wide ``MetricsRegistry`` of counters, gauges
     and fixed-exponential-bucket histograms (no per-observation
     allocation); every serving layer records into ``default_registry()``

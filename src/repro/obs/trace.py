@@ -27,8 +27,9 @@ iteration counts (device counters are only READ at boundaries the
 engine already synchronizes; tracing adds no extra device work).
 
 ``Phase`` is the other half: a named host phase of a loop (the engine's
-tick phases), written into the JAX profiler's trace as a span and summed
-into a plain float slot that the loop flushes into a metrics counter.
+tick phases), written into the JAX profiler's trace as a span and summed,
+as wall and as thread CPU time, into plain float slots that the loop
+flushes into metrics counters.
 """
 from __future__ import annotations
 
@@ -50,30 +51,40 @@ class Phase:
     """A named host phase of a loop, reusable as a context manager.
 
     Each ``with`` block is a ``jax.profiler.TraceAnnotation(name)`` span in
-    the profiler's trace (a no-op while no profiler runs) and adds its
-    ``time.perf_counter`` duration to ``acc[slot]``. Build one per (thread,
-    phase) up front: entering takes no lock and looks up no label (the
-    profiler's span object is its only allocation), and the only writer
-    of ``acc`` is the thread that owns it, which flushes the slots into a
-    metrics counter where it likes. Not re-entrant."""
+    the profiler's trace (a no-op while no profiler runs), adds its
+    ``time.perf_counter`` duration to ``wall[slot]`` and the calling
+    thread's CPU time over the same block (``time.thread_time``, read
+    inside the wall interval, so never more than it) to ``cpu[slot]``.
+    Wall minus CPU is the time the thread was runnable or blocked but not
+    running: waiting for the interpreter lock, another lock or a
+    transfer. Build one per (thread, phase) up front: entering takes no
+    lock and looks up no label (the profiler's span object is its only
+    allocation), and the only writer of ``wall`` and ``cpu`` is the
+    thread that owns them, which flushes the slots into metrics counters
+    where it likes. Not re-entrant."""
 
-    __slots__ = ("name", "acc", "slot", "_t0", "_span")
+    __slots__ = ("name", "wall", "cpu", "slot", "_t0", "_c0", "_span")
 
-    def __init__(self, name: str, acc: List[float], slot: int):
+    def __init__(self, name: str, wall: List[float], cpu: List[float],
+                 slot: int):
         self.name = name
-        self.acc = acc
+        self.wall = wall
+        self.cpu = cpu
         self.slot = slot
         self._t0 = 0.0
+        self._c0 = 0.0
         self._span = None
 
     def __enter__(self):
         self._span = TraceAnnotation(self.name)
         self._span.__enter__()
         self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
-        self.acc[self.slot] += time.perf_counter() - self._t0
+        self.cpu[self.slot] += time.thread_time() - self._c0
+        self.wall[self.slot] += time.perf_counter() - self._t0
         self._span.__exit__(*exc)
         self._span = None
         return False

@@ -87,8 +87,9 @@ __all__ = ["TopoRequest", "TopoFuture", "TopoServingEngine", "auto_shards",
            "shard_devices", "engine_from_spec", "PHASES"]
 
 # The phases of a shard's tick, in loop order. Each is a profiler span
-# ``topo.<phase>`` and a label of ``topo_host_seconds_total``; together
-# they tile the loop. sync: waits on the device; harvest: finished lanes'
+# ``topo.<phase>`` and a label of ``topo_host_seconds_total`` (wall) and
+# ``topo_host_cpu_seconds_total`` (the thread's CPU time); together they
+# tile the loop. sync: waits on the device; harvest: finished lanes'
 # reads, resolve and metrics; admit: scheduler lock, EDF pops, preemption
 # decision; park: a preemption's park and re-queue; rung: ladder width
 # changes with their lane moves; seed: parked-lane restores and the
@@ -178,6 +179,16 @@ def _lane_write_program(nelx: int, nely: int, masked: bool):
     return write
 
 
+def _lane_result(state: hybrid.HybridState, lane: int) -> tuple:
+    """A finished lane's result on the host: (density, compliance,
+    cronet_iters, fea_iters, cg_iters, cg_breakdowns), one eager read
+    each (syncs the device). The harvest's reads, and the ladder
+    warm-up's, so that both compile the same programs."""
+    return (np.asarray(state.x[lane]), float(state.compliance[lane]),
+            int(state.n_cronet[lane]), int(state.n_fea[lane]),
+            int(state.cg_iters[lane]), int(state.cg_breakdowns[lane]))
+
+
 def auto_shards(slots: int, device_count: Optional[int] = None) -> int:
     """Largest shard count <= device_count that divides `slots` while
     keeping shard width >= 2 (the minimum bitwise-invariant batch)."""
@@ -216,10 +227,12 @@ def shard_devices(slots: int, shards: Optional[int] = None,
 class _Shard:
     """One slot group: host-side slot constants + device-resident state,
     driven by exactly one tick-loop thread (lane bookkeeping is therefore
-    single-writer; only the EDF queue is shared)."""
+    single-writer; only the EDF queue is shared). ``index`` is its place
+    in the engine, the ``shard`` label of its counters."""
 
-    def __init__(self, engine: "TopoServingEngine", device):
+    def __init__(self, engine: "TopoServingEngine", index: int, device):
         self.engine = engine
+        self.index = index
         self.device = device
         cfg = engine.cfg
         L = engine.shard_width
@@ -251,11 +264,14 @@ class _Shard:
         self.steps_flushed = 0      # of which in topo_steps_total
         self.busy_t0: Optional[float] = None   # sync-point timing window
         self.steps_in_window = 0
-        # host seconds of the tick loop by phase since the last flush into
-        # topo_host_seconds_total; one reusable span per phase
+        # host wall and CPU seconds of the tick loop by phase since the
+        # last flush into topo_host_seconds_total and
+        # topo_host_cpu_seconds_total; one reusable span per phase
         self.host_s = [0.0] * len(PHASES)
-        self.phases = tuple(obs_trace.Phase("topo." + name, self.host_s, i)
-                            for i, name in enumerate(PHASES))
+        self.host_cpu_s = [0.0] * len(PHASES)
+        self.phases = tuple(
+            obs_trace.Phase("topo." + name, self.host_s, self.host_cpu_s, i)
+            for i, name in enumerate(PHASES))
 
     def activate(self):
         """Fresh idle state for a (re)started tick loop."""
@@ -501,7 +517,9 @@ class TopoServingEngine:
         self._rungs = (ladder_rungs(self.shard_width, self.ladder)
                        if self.ladder is not None else (self.shard_width,))
         self.shape_padded = shape_padded
-        self._warm_lock = threading.Lock()
+        # one warm-up lock per device: shards on distinct devices warm
+        # their ladders concurrently (compiles release the GIL)
+        self._warm_locks = {dev: threading.Lock() for dev in self._devices}
         self._warmed_devices: set = set()
         self.u_scale = u_scale
         self.precision = precision
@@ -521,7 +539,8 @@ class TopoServingEngine:
         self.tick_time_s = tick_time_s
         (self._edof, self._KE,
          self._penal, self._e_min) = _mesh_template(cfg.nelx, cfg.nely)
-        self._shards = [_Shard(self, dev) for dev in self._devices]
+        self._shards = [_Shard(self, i, dev)
+                        for i, dev in enumerate(self._devices)]
         self._sched = EDFScheduler(starvation_horizon)
         self._threads: List[threading.Thread] = []
         self._running = False
@@ -571,10 +590,16 @@ class TopoServingEngine:
             "accepted-but-unresolved requests per engine mesh")
         self._m_host = m.counter(
             "topo_host_seconds_total",
-            "host seconds of the shard tick loops by (mesh, phase)")
+            "host wall seconds of the shard tick loops by (mesh, shard, "
+            "phase)")
+        self._m_host_cpu = m.counter(
+            "topo_host_cpu_seconds_total",
+            "CPU seconds of the shard tick-loop threads by (mesh, shard, "
+            "phase)")
         self._m_steps = m.counter(
             "topo_steps_total",
-            "compiled steps dispatched by the shard tick loops, by mesh")
+            "compiled steps dispatched by the shard tick loops, by (mesh, "
+            "shard)")
         self._m_writes = m.counter(
             "topo_lane_writes_total",
             "compiled lane-write programs dispatched (dirty ticks and "
@@ -712,13 +737,19 @@ class TopoServingEngine:
         return self._rungs
 
     def _warm_ladder(self, device, params):
-        """Compile every ladder rung on ``device`` before traffic lands —
-        'compile-at-start of the whole ladder'. One idle step per rung;
-        the jit cache then serves every later rung change. Idempotent per
-        device (restarts skip it); no-op for ladder=None engines."""
+        """Compile, on ``device``, every program a tick can dispatch there,
+        before traffic lands — 'compile-at-start of the whole ladder'. Per
+        rung: one idle step, a harvest's lane reads (``_lane_result``), a
+        park and a restore, the resizes to every other rung, a lane move
+        and the lane write. Eager lane ops compile once per shape and
+        device, not per lane index, so lane 0 stands for every lane; the
+        jit cache then serves every later tick. Idempotent per device
+        (restarts skip it), under that device's own lock, so shards on
+        distinct devices warm concurrently; no-op for ladder=None
+        engines."""
         if self.ladder is None:
             return
-        with self._warm_lock:
+        with self._warm_locks[device]:
             if device in self._warmed_devices:
                 return
             states = {}
@@ -726,6 +757,10 @@ class TopoServingEngine:
                 bp = jax.device_put(self._shards[0]._idle_bp(r), device)
                 st = jax.device_put(hybrid.init_state(self.cfg, bp), device)
                 st = self.step(params, bp, fea2d.load_volume_b(bp), st)
+                jax.block_until_ready(st.it)
+                # a harvest's reads, then a preemption's park and restore
+                _lane_result(st, 0)
+                st = hybrid.restore_slot(st, 0, hybrid.park_slot(st, 0))
                 jax.block_until_ready(st.it)
                 states[r] = st
             # rung transitions dispatch un-jitted resize/compaction ops
@@ -860,33 +895,33 @@ class TopoServingEngine:
         adm.tr_base = (it, cro, fea, cg)
 
     def _flush_host(self, shard: _Shard):
-        """Move the shard's per-phase host seconds into
-        ``topo_host_seconds_total`` and its steps since the last flush
-        into ``topo_steps_total`` (tick-loop thread only)."""
-        acc = shard.host_s
+        """Move the shard's per-phase host wall and CPU seconds into
+        ``topo_host_seconds_total`` and ``topo_host_cpu_seconds_total``,
+        and its steps since the last flush into ``topo_steps_total``, each
+        under the shard's ``shard`` label (tick-loop thread only)."""
+        wall, cpu = shard.host_s, shard.host_cpu_s
         for i, name in enumerate(PHASES):
-            if acc[i]:
-                self._m_host.inc(acc[i], mesh=self._mesh_label, phase=name)
-                acc[i] = 0.0
+            if wall[i] or cpu[i]:
+                self._m_host.inc(wall[i], mesh=self._mesh_label,
+                                 shard=shard.index, phase=name)
+                self._m_host_cpu.inc(cpu[i], mesh=self._mesh_label,
+                                     shard=shard.index, phase=name)
+                wall[i] = cpu[i] = 0.0
         if shard.steps > shard.steps_flushed:
             self._m_steps.inc(shard.steps - shard.steps_flushed,
-                              mesh=self._mesh_label)
+                              mesh=self._mesh_label, shard=shard.index)
             shard.steps_flushed = shard.steps
 
     def _harvest_lane(self, shard: _Shard, lane: int, now: float):
         """Pull a finished lane's result (device sync) + resolve."""
         adm = shard.slot_adm[lane]
         req = adm.req
-        req.density = np.asarray(shard.state.x[lane])
+        (req.density, req.compliance, req.cronet_iters, req.fea_iters,
+         req.cg_iters, req.cg_breakdowns) = _lane_result(shard.state, lane)
         if req.orig_mesh is not None:
             # shape-class serving: crop the passive border back off so
             # the caller sees the mesh they submitted
             req.density = fea2d.crop_density(req.density, *req.orig_mesh)
-        req.compliance = float(shard.state.compliance[lane])
-        req.cronet_iters = int(shard.state.n_cronet[lane])
-        req.fea_iters = int(shard.state.n_fea[lane])
-        req.cg_iters = int(shard.state.cg_iters[lane])
-        req.cg_breakdowns = int(shard.state.cg_breakdowns[lane])
         req.model_tag = self.model_tag
         t_done = time.monotonic()    # deadline math: monotonic, like submit
         req.completed_t = time.time()  # user-facing wall-clock stamp
@@ -951,7 +986,8 @@ class TopoServingEngine:
     def _shard_loop(self, shard: _Shard):
         """One shard's tick loop. Each tick is a ``topo.tick`` step span in
         the profiler's trace, tiled by its ``PHASES`` spans; their host
-        seconds go into ``topo_host_seconds_total``, and the steps into
+        wall and CPU seconds go into ``topo_host_seconds_total`` and
+        ``topo_host_cpu_seconds_total``, and the steps into
         ``topo_steps_total``, every second dispatch and when the loop
         exits."""
         sched = self._sched

@@ -505,8 +505,9 @@ def test_tick_phases_tile_the_loop_and_the_profiler_is_invisible(
         time.sleep(0.3)          # idle ticks: the wait phase
         eng.shutdown()
         host = reg.counter("topo_host_seconds_total")
-        phases = {p: host.value(mesh="12x4", phase=p) for p in PHASES}
-        steps = reg.counter("topo_steps_total").value(mesh="12x4")
+        phases = {p: host.value(mesh="12x4", shard=0, phase=p)
+                  for p in PHASES}
+        steps = reg.counter("topo_steps_total").value(mesh="12x4", shard=0)
         assert steps == eng.total_steps > 0, (steps, eng.total_steps)
         return done, phases, ticks[-1][1] - ticks[0][0]
 
