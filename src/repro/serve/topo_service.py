@@ -89,13 +89,14 @@ __all__ = ["TopoRequest", "TopoFuture", "TopoServingEngine", "auto_shards",
 # The phases of a shard's tick, in loop order. Each is a profiler span
 # ``topo.<phase>`` and a label of ``topo_host_seconds_total`` (wall) and
 # ``topo_host_cpu_seconds_total`` (the thread's CPU time); together they
-# tile the loop. sync: waits on the device; harvest: finished lanes'
-# reads, resolve and metrics; admit: scheduler lock, EDF pops, preemption
-# decision; park: a preemption's park and re-queue; rung: ladder width
-# changes with their lane moves; seed: parked-lane restores and the
-# reset mask; upload: the lane-write program (slot constants to the
-# device, TrunkNet inputs, lane resets); dispatch: the compiled step and
-# the per-lane bookkeeping after it; wait: idle, no lane occupied.
+# tile the loop. sync: waits on the device; harvest: one host copy of
+# the finished lanes' results, resolve and metrics; admit: scheduler
+# lock, EDF pops, preemption decision; park: a preemption's park and
+# re-queue; rung: ladder width changes with their lane moves; seed:
+# parked-lane restores and the reset mask; upload: the lane-write
+# program (slot constants to the device, TrunkNet inputs, lane resets);
+# dispatch: the compiled step and the per-lane bookkeeping after it;
+# wait: idle, no lane occupied.
 PHASES = ("sync", "harvest", "admit", "park", "rung", "seed", "upload",
           "dispatch", "wait")
 
@@ -179,16 +180,6 @@ def _lane_write_program(nelx: int, nely: int, masked: bool):
     return write
 
 
-def _lane_result(state: hybrid.HybridState, lane: int) -> tuple:
-    """A finished lane's result on the host: (density, compliance,
-    cronet_iters, fea_iters, cg_iters, cg_breakdowns), one eager read
-    each (syncs the device). The harvest's reads, and the ladder
-    warm-up's, so that both compile the same programs."""
-    return (np.asarray(state.x[lane]), float(state.compliance[lane]),
-            int(state.n_cronet[lane]), int(state.n_fea[lane]),
-            int(state.cg_iters[lane]), int(state.cg_breakdowns[lane]))
-
-
 def auto_shards(slots: int, device_count: Optional[int] = None) -> int:
     """Largest shard count <= device_count that divides `slots` while
     keeping shard width >= 2 (the minimum bitwise-invariant batch)."""
@@ -260,6 +251,9 @@ class _Shard:
         self.bp = None
         self.load_vol = None
         self.state = None
+        # (state, its results on the host): the harvest's copy, made by
+        # the first lane_result call for a state and reused by the rest
+        self.harvest_copy = None
         self.steps = 0              # dispatched this activation
         self.steps_flushed = 0      # of which in topo_steps_total
         self.busy_t0: Optional[float] = None   # sync-point timing window
@@ -430,6 +424,28 @@ class _Shard:
         self.width = new_width
         self.rung_changes += 1
         return True
+
+    def lane_result(self, lane: int) -> tuple:
+        """A finished lane's result on the host: (density, compliance,
+        cronet_iters, fea_iters, cg_iters, cg_breakdowns), sliced from a
+        host copy of every lane's. The first call for the current
+        ``state`` makes the copy (counted in
+        ``topo_harvest_copies_total``): ``jax.device_get`` issues the six
+        whole arrays' transfers together and waits on them once, and runs
+        no program. Later calls for the same state reuse it, so a tick
+        that harvests several lanes copies once."""
+        st = self.state
+        if self.harvest_copy is None or self.harvest_copy[0] is not st:
+            self.harvest_copy = (st, jax.device_get(
+                (st.x, st.compliance, st.n_cronet, st.n_fea, st.cg_iters,
+                 st.cg_breakdowns)))
+            e = self.engine
+            e._m_harvest_copies.inc(mesh=e._mesh_label, shard=self.index)
+        x, compliance, n_cronet, n_fea, cg_iters, cg_breakdowns = \
+            self.harvest_copy[1]
+        return (x[lane].copy(), float(compliance[lane]),
+                int(n_cronet[lane]), int(n_fea[lane]), int(cg_iters[lane]),
+                int(cg_breakdowns[lane]))
 
     def park(self, lane: int) -> _Admission:
         """Evict the lane's occupant: lane-gather its state to host and
@@ -607,6 +623,10 @@ class TopoServingEngine:
         self._m_resets = m.counter(
             "topo_lanes_reset_total",
             "lanes re-seeded by the lane-write programs, by mesh")
+        self._m_harvest_copies = m.counter(
+            "topo_harvest_copies_total",
+            "device-to-host copies of the finished lanes' results the "
+            "harvest made, by (mesh, shard)")
         self.preemptions = 0        # engine lifetime eviction count
         self._steps_base = 0        # steps from finished activations
         self.last_run_steps = 0     # most recent run() only
@@ -739,9 +759,9 @@ class TopoServingEngine:
     def _warm_ladder(self, device, params):
         """Compile, on ``device``, every program a tick can dispatch there,
         before traffic lands — 'compile-at-start of the whole ladder'. Per
-        rung: one idle step, a harvest's lane reads (``_lane_result``), a
-        park and a restore, the resizes to every other rung, a lane move
-        and the lane write. Eager lane ops compile once per shape and
+        rung: one idle step, a park and a restore, the resizes to every
+        other rung, a lane move and the lane write (a harvest's host copy
+        runs no program). Eager lane ops compile once per shape and
         device, not per lane index, so lane 0 stands for every lane; the
         jit cache then serves every later tick. Idempotent per device
         (restarts skip it), under that device's own lock, so shards on
@@ -758,8 +778,7 @@ class TopoServingEngine:
                 st = jax.device_put(hybrid.init_state(self.cfg, bp), device)
                 st = self.step(params, bp, fea2d.load_volume_b(bp), st)
                 jax.block_until_ready(st.it)
-                # a harvest's reads, then a preemption's park and restore
-                _lane_result(st, 0)
+                # a preemption's park and restore
                 st = hybrid.restore_slot(st, 0, hybrid.park_slot(st, 0))
                 jax.block_until_ready(st.it)
                 states[r] = st
@@ -913,11 +932,12 @@ class TopoServingEngine:
             shard.steps_flushed = shard.steps
 
     def _harvest_lane(self, shard: _Shard, lane: int, now: float):
-        """Pull a finished lane's result (device sync) + resolve."""
+        """Pull a finished lane's result from the tick's host copy
+        (``_Shard.lane_result``) + resolve."""
         adm = shard.slot_adm[lane]
         req = adm.req
         (req.density, req.compliance, req.cronet_iters, req.fea_iters,
-         req.cg_iters, req.cg_breakdowns) = _lane_result(shard.state, lane)
+         req.cg_iters, req.cg_breakdowns) = shard.lane_result(lane)
         if req.orig_mesh is not None:
             # shape-class serving: crop the passive border back off so
             # the caller sees the mesh they submitted
@@ -957,8 +977,8 @@ class TopoServingEngine:
         self._m_cg.observe(req.cg_iters, mesh=self._mesh_label)
         if req.cg_breakdowns:
             self._m_cg_broke.inc(req.cg_breakdowns, mesh=self._mesh_label)
-        # the np.asarray above synced through every dispatched step:
-        # close the timing window and update the per-step estimate
+        # the harvest's copy waited on every dispatched step: close the
+        # timing window and update the per-step estimate
         if shard.steps_in_window > 0 and shard.busy_t0 is not None:
             per = (t_done - shard.busy_t0) / shard.steps_in_window
             self._sec_per_step = (per if self._sec_per_step is None
@@ -1038,7 +1058,8 @@ class TopoServingEngine:
                 if adm is not None and shard.slot_iters[i] >= adm.req.n_iter]
         if done:
             # the lane reads would wait for the device anyway: waiting
-            # here first leaves the harvest phase host work and transfers
+            # here first leaves the harvest phase host work and its one
+            # copy of the finished lanes' results
             with sync:
                 jax.block_until_ready(shard.state)
             with harvest:
