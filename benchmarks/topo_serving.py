@@ -1679,156 +1679,6 @@ def bench_observe(size: str = "small", smoke: bool = False,
         set_default_registry(prev)
 
 
-def bench_workers(n_workers: int = 2, size: str = "small",
-                  smoke: bool = False, check: bool = False):
-    """Multi-process worker leg (--workers N): engine pools live in
-    spawned worker processes, the ``TopoGateway`` stays the in-process
-    front end, and the two halves speak the length-prefixed pickle RPC
-    in ``repro.serve.workers``.
-
-    Always asserted (push budget with --smoke):
-      * a worker-served request is BITWISE-equal — density AND
-        harvested iteration counters — to the same problem run on an
-        in-process ``TopoServingEngine``: the RPC seam moves bytes,
-        never math;
-      * ``kill -9`` of a worker mid-tick loses zero requests: admitted
-        work fails with a typed ``WorkerLost`` naming the dead worker,
-        queued work transparently completes on the respawned
-        replacement, and ``worker-*`` fleet events narrate the loss,
-        reassign and requeue.
-
-    With --check (nightly budget): aggregate throughput over a
-    mixed-mesh drain must SCALE with worker count — every worker is
-    its own process with its own GIL and its own XLA host runtime, so
-    adding one buys a real core. The thread-sharded in-process
-    baseline has no such knob (all engine threads share one
-    interpreter lock); its number is measured for contrast and the
-    multi-worker pool must beat it too.
-    """
-    import signal
-
-    from repro.fea import fea2d
-    from repro.serve import (TopoGateway, TopoRequest, TopoServingEngine,
-                             WorkerLost)
-
-    cfg, params = _setup(size, hist_len=3)
-    meshes = [(12, 4), (10, 6)]
-    probs = {m: [fea2d.point_load_problem(
-        m[0], m[1], load_node=(i % (m[0] - 1), 0),
-        load=(0.0, -1.0 - 0.1 * i)) for i in range(8)]
-        for m in meshes}
-
-    def serve(workers, n_per_mesh, n_iter, base_uid):
-        """Drain n_per_mesh requests per mesh; return (done, thr/s).
-        ``workers=None`` is the thread-sharded in-process baseline."""
-        gw = TopoGateway(cfg, params, 50.0, slots=2, max_pending=256,
-                         workers=workers)
-        try:
-            warm = [gw.submit(TopoRequest(uid=base_uid + 9000 + j,
-                                          problem=probs[m][0], n_iter=2))
-                    for j, m in enumerate(meshes)]
-            for f in warm:                  # XLA compile / worker build
-                f.result(timeout=900)
-            futs, uid = [], base_uid
-            t0 = time.perf_counter()
-            for i in range(n_per_mesh):
-                for m in meshes:
-                    futs.append(gw.submit(TopoRequest(
-                        uid=uid, problem=probs[m][i % len(probs[m])],
-                        n_iter=n_iter)))
-                    uid += 1
-            done = [f.result(timeout=900) for f in futs]
-            dt = time.perf_counter() - t0
-            return done, len(done) / dt
-        finally:
-            gw.shutdown()
-
-    # 1. bitwise contract: worker-served == in-process engine
-    done, _ = serve(1, n_per_mesh=2, n_iter=6, base_uid=0)
-    for m in meshes:
-        sub = [r for r in done
-               if (r.problem.nelx, r.problem.nely) == m]
-        c = dataclasses.replace(cfg, nelx=m[0], nely=m[1])
-        eng = TopoServingEngine(c, params, 50.0, slots=2)
-        refs = eng.run([TopoRequest(uid=r.uid, problem=r.problem,
-                                    n_iter=r.n_iter) for r in sub])
-        eng.shutdown()
-        for r, ref in zip(sub, refs):
-            assert r.worker_id is not None, f"uid {r.uid}: no worker id"
-            assert np.array_equal(r.density, ref.density), \
-                f"uid {r.uid}: worker-served density != in-process"
-            assert (r.cronet_iters, r.fea_iters, r.cg_iters) == \
-                (ref.cronet_iters, ref.fea_iters, ref.cg_iters), \
-                f"uid {r.uid}: iteration counters diverged"
-    print(f"workers: bitwise worker-vs-in-process equality OK "
-          f"({len(done)} requests over {len(meshes)} meshes)")
-
-    # 2. crash contract: kill -9 mid-tick drops nothing
-    gw = TopoGateway(cfg, params, 50.0, slots=2, max_pending=32,
-                     workers=1, worker_pool_kwargs={"heartbeat_s": 0.5})
-    try:
-        futs = [gw.submit(TopoRequest(uid=100 + i,
-                                      problem=probs[(12, 4)][i],
-                                      n_iter=400 if i < 2 else 4))
-                for i in range(4)]
-        deadline = time.time() + 300
-        while time.time() < deadline:       # wait: 100-101 mid-tick
-            proxy = gw.engines.get((12, 4))
-            if proxy is not None:
-                with proxy._sched.cond:
-                    ents = [proxy._pending.get(100 + i) for i in (0, 1)]
-                if all(e is not None and e[2] for e in ents):
-                    break
-            time.sleep(0.05)
-        else:
-            raise AssertionError("long requests never admitted to ticks")
-        victim = gw._pool._workers[0]
-        os.kill(victim.proc.pid, signal.SIGKILL)
-        completed = lost = 0
-        for f in futs:
-            try:
-                r = f.result(timeout=600)
-                assert r.density is not None
-                completed += 1
-            except WorkerLost as exc:
-                assert exc.worker_id == victim.worker_id
-                lost += 1
-        assert completed + lost == len(futs), "a future was dropped"
-        assert completed >= 2 and lost >= 1, (completed, lost)
-        kinds = [e.kind for e in gw.fleet_events()]
-        for k in ("worker-lost", "worker-reassign", "worker-requeue"):
-            assert k in kinds, f"missing {k} in {kinds}"
-    finally:
-        gw.shutdown()
-    print(f"workers: kill -9 recovery OK ({completed} completed on the "
-          f"respawn, {lost} typed WorkerLost, zero dropped)")
-
-    # 3. scaling claim (nightly): more workers == more cores == more
-    # aggregate throughput; the in-process thread shard cannot follow
-    if check:
-        ncpu = os.cpu_count() or 1
-        if ncpu < 2:
-            print(f"workers: SKIPPING the scaling claim — this host has "
-                  f"{ncpu} CPU core and multi-core scaling needs >= 2 "
-                  f"(the bitwise + crash contracts above still gated)")
-            return
-        n_per_mesh, n_iter = 8, 10
-        _, thr_base = serve(None, n_per_mesh, n_iter, base_uid=20000)
-        _, thr_one = serve(1, n_per_mesh, n_iter, base_uid=40000)
-        _, thr_n = serve(n_workers, n_per_mesh, n_iter, base_uid=60000)
-        scale = thr_n / thr_one
-        print(f"workers: throughput in-process {thr_base:.2f}/s, "
-              f"1 worker {thr_one:.2f}/s, {n_workers} workers "
-              f"{thr_n:.2f}/s (scale {scale:.2f}x)")
-        assert scale >= 1.15, \
-            (f"{n_workers} workers only {scale:.2f}x over one worker "
-             f"({thr_n:.2f}/s vs {thr_one:.2f}/s)")
-        assert thr_n >= 1.15 * thr_base, \
-            (f"{n_workers} workers ({thr_n:.2f}/s) did not beat the "
-             f"thread-sharded in-process baseline ({thr_base:.2f}/s) "
-             f"by >= 1.15x")
-
-
 def run(fast: bool = True):
     """benchmarks/run.py suite entry."""
     r = bench(slots=8, n_requests=8 if fast else 24,
@@ -1883,15 +1733,6 @@ def main():
                          "structural gate only (bitwise equality + "
                          "interpret auto-detection, push budget); with "
                          "--check: nightly per-iteration latency claim")
-    ap.add_argument("--workers", type=int, nargs="?", const=2,
-                    default=None, metavar="N",
-                    help="multi-process worker leg: engine pools in N "
-                         "spawned worker processes behind one gateway. "
-                         "Always asserts bitwise worker-vs-in-process "
-                         "equality and kill -9 zero-drop recovery. "
-                         "With --check: nightly aggregate-throughput "
-                         "scaling claim vs one worker and vs the "
-                         "thread-sharded in-process baseline")
     ap.add_argument("--observe", action="store_true",
                     help="observability leg: trace_every=1 span tiling "
                          "(phases sum to e2e within 1%%) + bitwise "
@@ -1953,9 +1794,6 @@ def main():
                        finetune_steps=1000 if args.check else 300)
     elif args.observe:
         bench_observe(size=args.size, smoke=args.smoke, check=args.check)
-    elif args.workers is not None:
-        bench_workers(n_workers=args.workers, size=args.size,
-                      smoke=args.smoke, check=args.check)
     elif args.smoke:
         smoke()
     elif args.gateway:

@@ -28,10 +28,7 @@ Serving modes (same as before):
     (``--canary-fraction`` of admissions routed to a canary engine),
     reports the per-tag acceptance/deadline stats, and PROMOTEs the
     survivor — or surfaces the auto-rollback, if the canary regressed
-    against the concurrent primary traffic. ``--workers N`` moves the
-    engine pools into N spawned worker processes behind the same
-    gateway (real multi-core serving: each worker owns its own GIL and
-    XLA runtime; requests report which worker served them).
+    against the concurrent primary traffic.
 
 Flywheel mode (--flywheel, mixed-mesh only) arms the serving-data
 flywheel on the gateway: rejected traffic (requests the residual gate
@@ -163,12 +160,6 @@ def main():
                          "the registry, show the live metrics dashboard "
                          "during streaming runs, and print one sampled "
                          "request timeline at the end")
-    ap.add_argument("--workers", type=int, default=None, metavar="N",
-                    help="mixed-mesh mode: run the engine pools in N "
-                         "spawned worker processes behind the gateway "
-                         "(real multi-core serving — each worker owns "
-                         "its own GIL and XLA runtime; in-process "
-                         "engine threads otherwise)")
     args = ap.parse_args()
 
     from repro.common import use_compile_cache
@@ -252,8 +243,6 @@ def main():
             sys.exit("error: --flywheel drives its own canaries; "
                      "drop --canary")
         harvest_log = HarvestLog(capacity=64, accept_below=0.8)
-    if args.workers and not args.meshes:
-        sys.exit("error: --workers needs the gateway (--meshes AxB,...)")
     trace_every = 1 if args.observe else 0
     if args.meshes:
         service = TopoGateway.from_registry(
@@ -261,10 +250,8 @@ def main():
             max_pending=args.max_pending or None, overload=args.overload,
             error_threshold=args.threshold, backend=args.backend,
             preempt=not args.no_preempt, harvest=harvest_log,
-            canary_window=32, bucket_window=64, trace_every=trace_every,
-            workers=args.workers)
-        label = (f"gateway[{args.overload}]"
-                 + (f" x{args.workers} workers" if args.workers else ""))
+            canary_window=32, bucket_window=64, trace_every=trace_every)
+        label = f"gateway[{args.overload}]"
     else:
         params, record = registry.load(serve_tag)
         service = TopoServingEngine(
@@ -422,12 +409,10 @@ def main():
         mesh = (f"  {r.problem.nelx}x{r.problem.nely}"
                 if len(meshes) > 1 else "")
         tag = f"  [{r.model_tag}]" if args.swap else ""
-        wrk = (f"  w{r.worker_id}" if args.workers
-               and r.worker_id is not None else "")
         print(f"  req {r.uid:2d}:{mesh} compliance={r.compliance:9.2f}  "
               f"cronet {r.cronet_iters}/{total}  "
               f"latency {r.latency_s:.2f}s  queued {r.queue_wait_s:.2f}s"
-              f"{dl}{pre}{tag}{wrk}")
+              f"{dl}{pre}{tag}")
     for r in shed:
         print(f"  req {r.uid:2d}: SHED by the overload policy")
     for r in rejected:
@@ -464,13 +449,6 @@ def main():
             print(f"   {m[0]}x{m[1]}: {len(pool)} served, "
                   f"p50 {s['p50_latency_s']:.2f}s, "
                   f"CRONet {100 * s['cronet_hit_rate']:.1f}%")
-    if args.workers:
-        import collections
-        spread = collections.Counter(
-            r.worker_id for r in done if r.worker_id is not None)
-        print("== workers: "
-              + ", ".join(f"w{w} served {n}"
-                          for w, n in sorted(spread.items())) + " ==")
 
     if args.observe:
         from repro.obs import dashboard

@@ -178,36 +178,6 @@ never touches device math: densities are bitwise-identical with it on
 or off (``benchmarks/topo_serving.py --observe`` gates this, plus a
 <5% tick-latency overhead budget nightly).
 
-Multi-process engine workers (real multi-core scaling)::
-
-    from repro.serve import TopoGateway, TopoRequest, WorkerLost
-
-    gw = TopoGateway.from_registry(reg, "prod", slots=4,
-                                   workers=4)   # 4 engine processes
-    fut = gw.submit(TopoRequest(uid=0, problem=prob, n_iter=60))
-    req = fut.result()            # req.worker_id says which process
-    try:
-        other = gw.submit(...).result()
-    except WorkerLost as e:       # a worker died mid-tick: typed, with
-        retry(e.worker_id)        # the dead worker's id; never silent
-
-``workers=N`` moves the engine pool into N spawned worker processes
-(serve/workers.py) — one full Python/XLA runtime each, which is what
-genuine multi-core throughput scaling requires (tick-loop THREADS share
-one GIL and one XLA dispatch queue; ``benchmarks/topo_serving.py
---workers --check`` shows workers scaling where the thread-shard
-baseline stays flat). The gateway keeps the admission queue, routing,
-canaries, flywheel and leases; workers lease mesh buckets, build
-engines locally from the shared on-disk registry (or pickled params),
-and speak a length-prefixed pickle RPC over pipes. A request served
-through a worker is BITWISE-equal to the same request on an in-process
-engine. Robustness: heartbeats + deadline-aware RPC timeouts; on a
-worker crash, admitted in-flight requests fail with typed
-``WorkerLost`` while never-admitted ones requeue in EDF order onto a
-respawned worker (zero drops — every future resolves); ``worker-*``
-FleetEvents narrate spawn/lost/reassign/requeue, and completions carry
-``worker_id`` for per-worker observability.
-
 The LM-decode serving half (``server``, ``decode``) is deliberately NOT
 re-exported here: import those modules directly.
 """
@@ -221,9 +191,8 @@ from repro.serve.topo_service import TopoServingEngine
 from repro.serve.types import (EngineClosed, EngineState, FleetEvent,
                                GatewayOverloaded, OverloadPolicy,
                                QueueFull, RequestShed, TagStats,
-                               TopoFuture, TopoRequest, WorkerLost,
+                               TopoFuture, TopoRequest,
                                pool_stats, throughput_view)
-from repro.serve.workers import WorkerPool
 
 __all__ = [
     "TopoGateway",
@@ -247,8 +216,6 @@ __all__ = [
     "FlywheelCycle",
     "FlywheelState",
     "RegistryRetention",
-    "WorkerPool",
-    "WorkerLost",
     "pool_stats",
     "throughput_view",
 ]

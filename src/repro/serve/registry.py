@@ -45,9 +45,6 @@ Layout::
 
     <root>/registry.json          index: versions + metadata (atomic)
     <root>/ckpts/step_<version>/  one checkpoint per version (manager.py)
-    <root>/leases/<h>.<pid>.json  cross-process lease mirrors (one per
-                                  (tag, process); dead-pid files are
-                                  stale and reaped on the next scan)
 
 The index is the source of truth for metadata; the checkpoint manifest
 remains the source of truth for array bytes (hash-verified on load).
@@ -57,7 +54,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import datetime
-import hashlib
 import json
 import os
 import threading
@@ -121,12 +117,10 @@ class ModelRegistry:
     the registry."""
 
     INDEX = "registry.json"
-    LEASE_DIR = "leases"
 
     def __init__(self, root: str):
         self.root = root
         self.ckpt_dir = os.path.join(root, "ckpts")
-        self.lease_dir = os.path.join(root, self.LEASE_DIR)
         self._lock = threading.RLock()
         self._leases: Dict[str, int] = {}   # tag -> live refcount
         self._generation = 0                # bumped on every index write
@@ -293,98 +287,16 @@ class ModelRegistry:
         raise NoModelError(f"no model tagged {tag!r} in {self.root}")
 
     # ------------------------------------------------------------- leases
-    #
-    # Leases exist on two levels. The in-memory refcount map serves the
-    # single-process case (gateway threads). With engine-worker
-    # PROCESSES sharing one on-disk registry, each process additionally
-    # mirrors its refcounts into one lease FILE per (tag, pid) under
-    # ``<root>/leases/`` — ``prune``/``sweep`` in ANY process then defer
-    # tags that OTHER live processes are serving. A file whose writer
-    # pid is dead is stale (the process crashed before releasing) and is
-    # reaped on the next scan, so a kill -9'd worker cannot pin a
-    # version forever. All file I/O is best-effort: lease bookkeeping
-    # runs on shutdown/crash paths that must never raise.
-
-    def _lease_path(self, tag: str, pid: Optional[int] = None) -> str:
-        pid = os.getpid() if pid is None else pid
-        h = hashlib.sha1(tag.encode()).hexdigest()[:12]
-        return os.path.join(self.lease_dir, f"{h}.{pid}.json")
-
-    def _write_lease_file(self, tag: str, count: int):
-        """Mirror this process's refcount for ``tag`` to disk (atomic
-        tmp + replace; count <= 0 removes the file)."""
-        try:
-            path = self._lease_path(tag)
-            if count <= 0:
-                try:
-                    os.unlink(path)
-                except FileNotFoundError:
-                    pass
-                return
-            os.makedirs(self.lease_dir, exist_ok=True)
-            tmp = path + ".tmp"
-            with open(tmp, "w") as f:
-                json.dump({"tag": tag, "pid": os.getpid(),
-                           "count": int(count)}, f)
-            os.replace(tmp, path)
-        except OSError:
-            pass
-
-    @staticmethod
-    def _pid_alive(pid: int) -> bool:
-        try:
-            os.kill(pid, 0)
-        except ProcessLookupError:
-            return False
-        except (PermissionError, OSError):
-            return True   # exists but owned elsewhere: alive
-        return True
-
-    def foreign_leases(self) -> Dict[str, int]:
-        """Tags leased by OTHER live processes sharing this registry
-        root (scanned from the lease files), with stale dead-pid files
-        reaped as a side effect. This process's own leases are reported
-        by ``leased()`` — the in-memory map is authoritative for them."""
-        out: Dict[str, int] = {}
-        try:
-            names = os.listdir(self.lease_dir)
-        except OSError:
-            return out
-        me = os.getpid()
-        for name in names:
-            if not name.endswith(".json"):
-                continue
-            path = os.path.join(self.lease_dir, name)
-            try:
-                with open(path) as f:
-                    rec = json.load(f)
-                tag, pid = rec["tag"], int(rec["pid"])
-                count = int(rec.get("count", 1))
-            except (OSError, ValueError, KeyError, TypeError):
-                continue   # torn write mid-crash: ignore, never raise
-            if pid == me:
-                continue
-            if not self._pid_alive(pid):
-                try:
-                    os.unlink(path)   # crashed holder: reap the lease
-                except OSError:
-                    pass
-                continue
-            out[tag] = out.get(tag, 0) + count
-        return out
 
     def acquire(self, tag: str) -> ModelRecord:
         """Mark a version LIVE (being served or canaried): ``prune``
         defers it until every acquirer has ``release``d. Refcounted —
         a gateway serving a tag in three buckets acquires it three
         times. Raises ``NoModelError`` for an unknown tag (a lease on
-        nothing would silently protect nothing). The refcount is
-        mirrored to a per-process lease file so prune/sweep in OTHER
-        processes sharing this root defer the tag too."""
+        nothing would silently protect nothing)."""
         rec = self.get(tag)
         with self._lock:
             self._leases[tag] = self._leases.get(tag, 0) + 1
-            self._write_lease_file(tag, self._leases[tag])
         return rec
 
     def release(self, tag: str):
@@ -396,11 +308,9 @@ class ModelRegistry:
                 self._leases[tag] = n
             else:
                 self._leases.pop(tag, None)
-            self._write_lease_file(tag, max(n, 0))
 
     def leased(self) -> Dict[str, int]:
-        """Live tags and their refcounts (snapshot; THIS process only —
-        see ``foreign_leases()`` for other processes on the same root)."""
+        """Live tags and their refcounts (snapshot)."""
         with self._lock:
             return dict(self._leases)
 
@@ -412,10 +322,8 @@ class ModelRegistry:
         become reclaimable once released). Returns the pruned tags."""
         with self._lock:
             index = self._read_index()
-            foreign = self.foreign_leases()
             pinned = [int(e["version"]) for e in index["versions"]
-                      if e.get("pinned") or self._leases.get(e["tag"])
-                      or foreign.get(e["tag"])]
+                      if e.get("pinned") or self._leases.get(e["tag"])]
             removed = set(ckpt.prune_old(self.ckpt_dir, keep=keep,
                                          pinned=pinned))
             dropped = [e["tag"] for e in index["versions"]
@@ -457,11 +365,9 @@ class ModelRegistry:
                 mesh = tuple(e["mesh"]) if e.get("mesh") else None
                 key = (mesh, self._lineage_root(entries, e["tag"]))
                 groups.setdefault(key, []).append(e)
-            foreign = self.foreign_leases()
             keep_versions = set()
             for e in entries:
-                if e.get("pinned") or self._leases.get(e["tag"]) \
-                        or foreign.get(e["tag"]):
+                if e.get("pinned") or self._leases.get(e["tag"]):
                     keep_versions.add(int(e["version"]))
             for members in groups.values():
                 # entries are index-ordered (oldest first): the newest K
@@ -581,22 +487,6 @@ class ModelResolver:
         with self._lock:
             self._check_generation_locked()
             self._put(tag, params, record)
-
-    def holds(self, tag: Optional[str], params) -> bool:
-        """True iff ``params`` IS (identity, not equality) the cached
-        param tree for ``tag``. The worker-mode gateway uses this to
-        decide whether an engine spec may ship a ``registry_root``
-        reference instead of the pickled tree: only when the params
-        provably came from this resolver's registry read is a
-        worker-side re-read guaranteed to reproduce them — an
-        explicit-params pin under a registered tag must still travel by
-        value or the bitwise contract breaks."""
-        if tag is None:
-            return False
-        with self._lock:
-            self._check_generation_locked()
-            hit = self._cache.get(tag)
-        return hit is not None and hit[0] is params
 
     def load(self, tag: str) -> Tuple[object, ModelRecord]:
         """Materialize a tag's params (LRU-cached per tag; the cache is

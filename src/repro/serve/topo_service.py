@@ -84,7 +84,7 @@ from repro.serve.types import (EngineClosed, EngineState, TopoFuture,
                                TopoRequest, pool_stats)
 
 __all__ = ["TopoRequest", "TopoFuture", "TopoServingEngine", "auto_shards",
-           "shard_devices", "engine_from_spec", "PHASES"]
+           "shard_devices", "PHASES"]
 
 # The phases of a shard's tick, in loop order. Each is a profiler span
 # ``topo.<phase>`` and a label of ``topo_host_seconds_total`` (wall) and
@@ -1261,51 +1261,3 @@ class TopoServingEngine:
                                         for sh in self._shards)),
             }
         return stats
-
-
-# ------------------------------------------------------------- worker build
-
-
-def engine_from_spec(spec: Dict) -> "TopoServingEngine":
-    """Build a ``TopoServingEngine`` from a picklable description — the
-    ONE engine factory the multi-process serving path reuses in-worker
-    (serve/workers.py ships a spec over the RPC pipe instead of a live
-    engine, which could never pickle its threads/locks/device buffers).
-
-    ``spec`` keys:
-
-      * ``cfg`` — the bucket's ``CRONetConfig`` (already mesh-replaced).
-      * ``params`` / ``u_scale`` — explicit model arrays; OR
-      * ``registry_root`` + ``model_tag`` — load the params from the
-        shared on-disk ``ModelRegistry`` instead of pickling the full
-        tree through the pipe (the cross-process deployment shape: one
-        registry, many workers, params read once per worker).
-      * ``slots`` / ``model_tag`` / ``ladder`` / ``shape_padded`` —
-        engine geometry, verbatim ctor kwargs.
-      * ``engine_kwargs`` — remaining ``TopoServingEngine`` kwargs
-        (``fea_backend``, ``precision``, ``preempt``, ...).
-
-    Because construction runs through the same ctor with the same
-    params, a worker-built engine's densities are bitwise-equal to an
-    in-process engine's for the same requests — the multi-process path
-    moves WHERE the engine runs, never what it computes.
-    """
-    cfg = spec["cfg"]
-    params = spec.get("params")
-    u_scale = spec.get("u_scale")
-    tag = spec.get("model_tag")
-    if params is None:
-        root = spec.get("registry_root")
-        if root is None:
-            raise ValueError("engine spec needs params or registry_root")
-        from repro.serve.registry import ModelRegistry
-        params, rec = ModelRegistry(root).load(tag)
-        tag = rec.tag
-        u_scale = u_scale if u_scale is not None else rec.u_scale
-    return TopoServingEngine(
-        cfg, params, u_scale,
-        slots=int(spec.get("slots", 8)),
-        model_tag=tag,
-        ladder=spec.get("ladder"),
-        shape_padded=bool(spec.get("shape_padded", False)),
-        **dict(spec.get("engine_kwargs") or {}))

@@ -74,19 +74,6 @@ class RequestShed(GatewayOverloaded):
     bounded queue in favour of more-urgent work; its future raises this."""
 
 
-class WorkerLost(RuntimeError):
-    """An engine-worker process died while this request was in flight on
-    one of its ticks. Requests that had NOT yet been admitted to a slot
-    when the worker died are transparently requeued (preserving their
-    EDF rank) instead of raising this — only work that genuinely
-    progressed on the lost worker fails typed, so the caller knows a
-    retry re-runs iterations rather than resuming them."""
-
-    def __init__(self, msg: str, worker_id: Optional[int] = None):
-        super().__init__(msg)
-        self.worker_id = worker_id
-
-
 class OverloadPolicy(enum.Enum):
     """What a full bounded admission queue does with the next submit."""
     BLOCK = "block"
@@ -159,10 +146,6 @@ class TopoRequest:
     # satisfy ``model_tag == routed_tag`` — the engine that served it is
     # the engine it was routed to (the fleet tests' mis-tag invariant).
     routed_tag: Optional[str] = None
-    # filled on completion when served through a WorkerPool: the id of
-    # the worker process whose engine ran the ticks (None for in-process
-    # serving) — the label the obs layer splits per-worker metrics on.
-    worker_id: Optional[int] = None
 
     @property
     def mesh(self) -> tuple:
