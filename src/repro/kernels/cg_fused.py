@@ -13,28 +13,37 @@ iteration counts and the breakdown flags.
 
 Layout. The kernel works on the reference's own flat dof vector (node
 ``n = x*(nely+1) + y``, dofs ``[2n, 2n+1]``), slots on sublanes and dofs
-on lanes, zero-padded to the power of two that ``fea2d.tree_sum`` pads
-to (at least one 128-lane row). Every block is then a lane-dense
-``(B, L)`` tile, and the structured-mesh stencil needs no reshape: an
-element's eight local dofs sit at fixed lane offsets from its first
-node's x-dof (its *anchor*), so gathering them is eight lane rotations
-of the search direction, and assembly is four rotations back. The SIMP
-stiffness lives on the anchor lanes (zero everywhere else), which also
-zeroes whatever a rotation wraps around the end of the row.
+on lanes, zero-padded to whole 128-lane rows (``_lanes``; 60x20: 2688
+lanes for 2562 dofs), not to the power of two that ``fea2d.tree_sum``
+pads to: only a reduction's first fold sees that pad. Every block is
+then a lane-dense ``(B, L)`` tile, and the structured-mesh stencil needs
+no reshape: an element's eight local dofs sit at fixed lane offsets from
+its first node's x-dof (its *anchor*), so gathering them is eight lane
+rotations of the search direction, and assembly is four rotations back.
+The SIMP stiffness lives on the anchor lanes (zero everywhere else). A
+rotation's wrap around the end of the row is harmless for any
+``L >= ndof``: the last anchor plus the largest local offset is
+``ndof - 1``, so no anchor lane reads across the end, every wrapped
+value lands on a lane whose stiffness is zero, and the assembly
+rotations move only values sitting at most one lane past an anchor,
+which land at most on lane ``ndof - 1``.
 
 Arithmetic order. The kernel repeats the reference's operations in the
 reference's order: the unrolled ``_ke_apply`` contraction, the
 ``(c1 + c2) + (c3 + c4)`` assembly sum, and the pairwise halving tree of
-``tree_sum`` (folded with aligned lane slices down to one 128-lane row,
-then with rotations inside it). So under jit on the CPU, where the
-kernel runs through the Pallas interpreter, ``solve_b(...,
-backend="fused")`` is bitwise-equal to the reference path
-(tests/test_cg_fused.py sweeps widths, ``need`` masks, zero-load slots
-and ``elem_mask`` padding). The SIMP stiffness is recomputed inside the
-kernel from the densities: handing it in precomputed changes how XLA
-contracts the ``e * stencil`` products on the CPU and flips last-ulp
-bits. Compiled for a TPU the same contract is not claimed; there the
-path is held to a tolerance against the reference (chip_smoke.py).
+``tree_sum`` (the reference's zero pad added for the first fold, then
+aligned lane slices down to one 128-lane row, then rotations inside
+it). So under jit on the CPU, where the kernel runs through the Pallas
+interpreter, ``solve_b(..., backend="fused")`` is bitwise-equal to the
+reference path (tests/test_cg_fused.py sweeps widths, row widths,
+``need`` masks, zero-load slots and ``elem_mask`` padding; at 23x7 it
+holds only with XLA's CPU optimisation off, where LLVM contracts the
+two paths' multiply-adds alike). The SIMP stiffness is recomputed
+inside the kernel from the densities: handing it in precomputed changes
+how XLA contracts the ``e * stencil`` products on the CPU and flips
+last-ulp bits. Compiled for a TPU the same contract is not claimed;
+there the path is held to a tolerance against the reference
+(chip_smoke.py).
 
 The whole slot batch rides in one grid step: a width-1 per-slot block
 would lower differently from the reference's batched ops on the CPU.
@@ -58,8 +67,8 @@ from repro.kernels import resolve_interpret
 
 
 def _lanes(ndof: int) -> int:
-    """Padded dof width: fea2d.tree_sum's power of two, at least 128."""
-    return max(128, 1 << max(ndof - 1, 0).bit_length())
+    """Padded dof width: whole 128-lane rows, at least one."""
+    return max(128, -(-ndof // 128) * 128)
 
 
 def _local_offsets(nely: int):
@@ -70,8 +79,17 @@ def _local_offsets(nely: int):
 
 
 def _tree_sum(x):
-    """fea2d.tree_sum over the lanes of a (B, L) block, L a power of two
-    >= 128, in the reference's pairing order. Returns (B, 1)."""
+    """fea2d.tree_sum over the lanes of a (B, L) block, L a multiple of
+    128, in the reference's pairing order. Returns (B, 1)."""
+    n = x.shape[-1]
+    p = 1 << (n - 1).bit_length()      # the reference's padded width
+    if p != n:
+        # the reference's zero pad, for the first fold alone. Passing the
+        # lanes that meet only zeros through unchanged would sum the same,
+        # but XLA on the CPU then contracts the product into that fold's
+        # add where the reference does not, and the two round apart
+        x = jnp.concatenate([x, jnp.zeros((x.shape[0], p - n), x.dtype)],
+                            axis=1)
     while x.shape[-1] > 128:
         half = x.shape[-1] // 2
         x = x[:, :half] + x[:, half:]

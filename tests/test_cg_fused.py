@@ -45,12 +45,14 @@ def _probs(n, nelx=12, nely=4):
         load=(0.05 * i, -1.0 - 0.1 * i)) for i in range(n)]
 
 
-def _solve_both(bp, X, need=None):
+def _solve_both(bp, X, need=None, **kw):
     # jitted with (bp, X, ...) as traced arguments — the same calling
     # convention as the engine's compiled tick, the contract's domain
-    ref = jax.jit(lambda b, x, n: fea2d.solve_b(b, x, need=n))(bp, X, need)
+    ref = jax.jit(lambda b, x, n: fea2d.solve_b(b, x, need=n, **kw))(
+        bp, X, need)
     fus = jax.jit(lambda b, x, n: fea2d.solve_b(b, x, need=n,
-                                                backend="fused"))(bp, X, need)
+                                                backend="fused", **kw))(
+        bp, X, need)
     return ref, fus
 
 
@@ -199,3 +201,126 @@ def test_fused_engine_bitwise_and_streaming_cache_hit():
     for i, (a, b) in enumerate(zip(dens["reference"], dens["fused"])):
         np.testing.assert_array_equal(
             a, b, err_msg=f"request {i}: fused-engine density diverged")
+
+
+# ------------------------------------------- row width: whole 128-lane rows
+
+
+def _kernel_tree_sum(x):
+    """cg_fused._tree_sum over a (B, L) block, through the interpreter."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import cg_fused
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = cg_fused._tree_sum(x_ref[...])
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32),
+        interpret=True)(x)
+
+
+@pytest.mark.parametrize("lanes,ndof", [(128, 100), (256, 130), (384, 330),
+                                        (768, 682), (2688, 2562),
+                                        (4096, 3000)])
+def test_tree_sum_row_widths_bitwise(lanes, ndof):
+    """The kernel's fold over a row of whole 128-lane rows, power of two
+    or not, sums in fea2d.tree_sum's pairing order: bitwise-equal to the
+    reference over the first ``ndof`` lanes, on values of both signs whose
+    exponents span 2**-8 .. 2**8 (wide enough that a misplaced pairing
+    rounds differently, narrow enough that no value is lost outright)
+    with zeros beyond ``ndof``."""
+    rng = np.random.default_rng(lanes)
+    vals = (rng.choice([-1.0, 1.0], (8, ndof)) * rng.random((8, ndof))
+            * np.exp2(rng.integers(-8, 9, (8, ndof)))).astype(np.float32)
+    row = np.zeros((8, lanes), np.float32)
+    row[:, :ndof] = vals
+    got = jax.jit(_kernel_tree_sum)(jnp.asarray(row))[:, 0]
+    want = jax.jit(fea2d.tree_sum)(jnp.asarray(vals))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("ndof,lanes", [(130, 256), (384, 384), (682, 768),
+                                        (2562, 2688)])
+def test_lanes_cover_ndof(ndof, lanes):
+    """The fused solve's row: the mesh's dofs in whole 128-lane rows."""
+    from repro.kernels import cg_fused
+
+    assert cg_fused._lanes(ndof) == lanes
+
+
+ROW_MESHES = {"30x10": (30, 10, 2000), "23x7": (23, 7, 2000),
+              "60x20": (60, 20, 300)}
+
+
+def _row_case(nelx, nely):
+    """Three slots on an nelx x nely mesh, densities drawn in [0.1, 1)."""
+    X = jax.random.uniform(jax.random.key(nelx), (3, nely, nelx),
+                           minval=0.1, maxval=1.0)
+    return fea2d.stack_problems(_probs(3, nelx, nely)), X
+
+
+ROW_SCRIPT = r"""
+import json, sys
+import numpy as np
+from test_cg_fused import _row_case, _solve_both
+out = {}
+for mesh, (nelx, nely, max_iter) in json.loads(sys.argv[1]).items():
+    (ur, ir, br), (uf, itf, bf) = (
+        [np.asarray(a) for a in r]
+        for r in _solve_both(*_row_case(nelx, nely), max_iter=max_iter))
+    out[mesh] = {"u_bitwise": bool(np.array_equal(ur, uf)),
+                 "iters": [ir.tolist(), itf.tolist()],
+                 "broke": [br.tolist(), bf.tolist()]}
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def row_width_solves():
+    import json
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    root = os.path.dirname(here)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_backend_optimization_level=0",
+               PYTHONPATH=os.pathsep.join([os.path.join(root, "src"), here]))
+    proc = subprocess.run(
+        [sys.executable, "-c", ROW_SCRIPT, json.dumps(ROW_MESHES)], env=env,
+        capture_output=True, text=True, timeout=300, cwd=root)
+    assert proc.returncode == 0, proc.stderr[-2500:]
+    line = next(l for l in proc.stdout.splitlines()
+                if l.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("mesh", ["30x10", "23x7", "60x20"])
+def test_fused_bitwise_row_widths(row_width_solves, mesh):
+    """Whole solves on rows that are not a power of two (30x10: 768
+    lanes; 23x7: 384, no dead lane, so every rotation's wrap is live;
+    60x20: 2688): fused == reference bitwise, iteration counts included.
+
+    Run in a subprocess with XLA's CPU backend optimisation off
+    (``--xla_backend_optimization_level=0``): at the default level, which
+    multiply-adds LLVM contracts depends on the shapes of each fused
+    loop, and at 23x7 the two paths round apart for reasons outside the
+    arithmetic they spell out (the power-of-two row did so too). The
+    default level is held at 30x10 and 60x20 by the next test."""
+    got = row_width_solves[mesh]
+    assert got["u_bitwise"], f"{mesh}: U diverged"
+    assert got["iters"][0] == got["iters"][1], got["iters"]
+    assert got["broke"][0] == got["broke"][1], got["broke"]
+    assert all(i > 0 for i in got["iters"][0])
+
+
+@pytest.mark.parametrize("mesh", ["30x10", "60x20"])
+def test_fused_bitwise_row_widths_default_level(mesh):
+    """At the configurations' meshes the contract also holds at XLA's
+    default CPU optimisation level, on rows that are not a power of two:
+    fused == reference bitwise, iteration counts included."""
+    nelx, nely, max_iter = ROW_MESHES[mesh]
+    _assert_bitwise(*_solve_both(*_row_case(nelx, nely), max_iter=max_iter),
+                    msg=mesh)
